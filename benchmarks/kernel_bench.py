@@ -20,7 +20,7 @@ Outputs:
     baseline the perf trajectory tracks across PRs (--out to relocate)
   * `--retune` — sweep candidate `Tile`s per cell and rewrite the shipped
     `kernels/tune_cpu.json` TuneTable (the "autotune per operating point"
-    data file; rerun on real TPU hardware with interpret off)
+    data file; on a TPU host the kernels compile instead of interpreting)
 """
 from __future__ import annotations
 
@@ -139,8 +139,7 @@ def _paged_attn_rows():
         q, kp, vp, pages, pos = _paged_attn_problem(page_size, table_pages,
                                                     active)
         us = _time_us(lambda: pa.paged_flash_decode(
-            q, kp, vp, pages, pos, pages_per_block=bkp,
-            interpret=dispatch.INTERPRET))
+            q, kp, vp, pages, pos, pages_per_block=bkp))
         rows.append({
             "op": {"wprec": "paged_attn", "aprec": "decode", "impl": "*",
                    "backend": "pallas"},
@@ -155,8 +154,8 @@ def _paged_attn_rows():
 
 def retune(out_path: str, reps: int = 2) -> TuneTable:
     """Sweep candidate Tiles per Pallas cell, keep the fastest, save a
-    TuneTable. Interpret-mode-on-CPU numbers — a structural baseline; rerun
-    with REPRO_PALLAS_INTERPRET=0 on real hardware for production tables."""
+    TuneTable. On CPU the kernels run interpreted, so the numbers are a
+    structural baseline; run on a TPU host for production tables."""
     tiles: dict[tuple, Tile] = {}
     for key in sorted(dispatch.cells()):
         cell = dispatch.cells()[key]
@@ -184,8 +183,7 @@ def retune(out_path: str, reps: int = 2) -> TuneTable:
     best_bkp, best_us = None, float("inf")
     for bkp in (1, 2, 4, 8):
         us = _time_us(lambda: pa.paged_flash_decode(
-            q, kp, vp, pages, pos, pages_per_block=bkp,
-            interpret=dispatch.INTERPRET), reps=reps)
+            q, kp, vp, pages, pos, pages_per_block=bkp), reps=reps)
         if us < best_us:
             best_bkp, best_us = bkp, us
     tiles[pa.TUNE_KEY] = Tile(1, 1, best_bkp)
@@ -243,7 +241,7 @@ def main(argv=None):
     if not args.no_json:
         with open(args.out, "w") as f:
             json.dump({"bench": "dispatch_qgemm", "m": M, "k": K, "n": N,
-                       "interpret": dispatch.INTERPRET,
+                       "interpret": dispatch.pallas_interpret(),
                        "tune_source": dispatch.default_tune().source,
                        "rows": rows}, f, indent=2)
             f.write("\n")

@@ -279,7 +279,6 @@ def decode_attn_rows(active_lens=(128, 512, 1024, 2048, 4096), *, slots=4,
     import jax.numpy as jnp
 
     from repro.kernels import paged_attn as pa
-    from repro.kernels.dispatch import INTERPRET
     from repro.models.attention import KV_SCALE
 
     num_pages = 1 + slots * table_pages
@@ -326,7 +325,7 @@ def decode_attn_rows(active_lens=(128, 512, 1024, 2048, 4096), *, slots=4,
              lambda: gather(pages[:, :act_pages], pos), act_b),
             ("fused", lambda: pa.paged_flash_decode(
                 q, kp, vp, pages, pos, pages_per_block=bkp,
-                kv_scale=KV_SCALE, interpret=INTERPRET), act_b),
+                kv_scale=KV_SCALE), act_b),
         ):
             rows.append(dict(scenario="decode-attn", config=name,
                              active_len=al, us_per_step=time_us(fn),

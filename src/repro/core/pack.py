@@ -69,11 +69,18 @@ def pack_bits(codes: jnp.ndarray) -> jnp.ndarray:
     return words.astype(jnp.uint32)
 
 
+def unpack_bits_i32(words: jnp.ndarray, k: int) -> jnp.ndarray:
+    """0/1 codes as int32 with last axis k. The int8 decoders below do their
+    arithmetic here and narrow once at the end: the TPU vector unit has no
+    int8 multiply, so int8 arithmetic does not lower inside a kernel."""
+    shifts = jnp.arange(WORD, dtype=jnp.uint32)
+    bits = ((words[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
+    return bits.reshape(*words.shape[:-1], words.shape[-1] * WORD)[..., :k]
+
+
 def unpack_bits(words: jnp.ndarray, k: int) -> jnp.ndarray:
     """Inverse of pack_bits -> uint8 codes with last axis k."""
-    shifts = jnp.arange(WORD, dtype=jnp.uint32)
-    bits = (words[..., None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(*words.shape[:-1], words.shape[-1] * WORD)[..., :k].astype(jnp.uint8)
+    return unpack_bits_i32(words, k).astype(jnp.uint8)
 
 
 # -- binary ------------------------------------------------------------------
@@ -95,8 +102,7 @@ def unpack_pm1_i8(words: jnp.ndarray, k: int) -> jnp.ndarray:
     The canonical plane->operand decoder for the MXU formulations (jnp and
     Pallas tile bodies both call this — one unpack implementation total).
     """
-    bits = unpack_bits(words, k)
-    return bits.astype(jnp.int8) * 2 - 1
+    return (unpack_bits_i32(words, k) * 2 - 1).astype(jnp.int8)
 
 
 # -- ternary -----------------------------------------------------------------
@@ -118,9 +124,9 @@ def unpack_ternary(mask_words: jnp.ndarray, sign_words: jnp.ndarray, k: int) -> 
 def unpack_ternary_i8(mask_words: jnp.ndarray, sign_words: jnp.ndarray,
                       k: int) -> jnp.ndarray:
     """Unpack trit planes to {-1,0,+1} int8 (canonical MXU-path decoder)."""
-    mask = unpack_bits(mask_words, k).astype(jnp.int8)
-    sign = unpack_bits(sign_words, k).astype(jnp.int8)
-    return mask * (1 - 2 * sign)
+    mask = unpack_bits_i32(mask_words, k)
+    sign = unpack_bits_i32(sign_words, k)
+    return (mask * (1 - 2 * sign)).astype(jnp.int8)
 
 
 # -- int4 (s4 nibble codes, 8 per word) --------------------------------------

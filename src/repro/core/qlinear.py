@@ -119,14 +119,11 @@ def _packed_wire_weight(w: jnp.ndarray, spec: QLinearSpec) -> jnp.ndarray:
     def rep(t):
         """Pin replicated-over-data (out-dim stays on model) — forces the
         FSDP all-gather HERE, on the packed planes. No-op without a mesh."""
-        try:
-            mesh = jax.sharding.get_abstract_mesh()
-            if mesh is None or "model" not in (mesh.axis_names or ()):
-                return t
-            return jax.lax.with_sharding_constraint(
-                t, P(*([None] * (t.ndim - 1)), "model"))
-        except Exception:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh is None or "model" not in (mesh.axis_names or ()):
             return t
+        return jax.lax.with_sharding_constraint(
+            t, P(*([None] * (t.ndim - 1)), "model"))
 
     wq = jax.lax.stop_gradient(w)
     if prec == "ternary":

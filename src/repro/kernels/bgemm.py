@@ -27,15 +27,15 @@ WORD = 32
 
 
 def _popcount_step(xs, ws, accs, *, bkq):
-    x, w = xs[0], ws[0]                     # (bm, bkq), (bn, bkq) uint32
-
-    def body(i, acc):
-        xi = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=1)        # (bm, 1)
-        wi = jax.lax.dynamic_slice_in_dim(w, i, 1, axis=1)        # (bn, 1)
-        mism = jax.lax.population_count(jnp.bitwise_xor(xi, wi.T))
-        return acc + mism.astype(jnp.int32)
-
-    return (jax.lax.fori_loop(0, bkq, body, accs[0]),)
+    x, wt = xs[0], ws[0].T                  # (bm, bkq), (bkq, bn) uint32
+    acc = accs[0]
+    # one packed word per step, unrolled: static lane/sublane slices are what
+    # Mosaic lowers (a dynamic lane slice is not)
+    for i in range(bkq):
+        mism = jax.lax.population_count(
+            jnp.bitwise_xor(x[:, i:i + 1], wt[i:i + 1, :]))   # (bm, bn)
+        acc = acc + mism.astype(jnp.int32)
+    return (acc,)
 
 
 def _popcount_finish(accs, k_total):
@@ -64,7 +64,7 @@ BINARY_MXU = MacBody("bgemm_mxu", n_x=1, n_w=1, n_acc=1, k_per_q=WORD,
 def bgemm(x_packed: jnp.ndarray, w_packed: jnp.ndarray,
           w_scale: jnp.ndarray, a_scale: jnp.ndarray, *, k: int,
           bm: int = 128, bn: int = 128, bkw: int = 16,
-          impl: str = "popcount", interpret: bool = True) -> jnp.ndarray:
+          impl: str = "popcount", interpret: bool | None = None) -> jnp.ndarray:
     """Packed binary GEMM: (M, K/32)u32 × (N, K/32)u32 → (M, N) bf16."""
     body = BINARY_POPCOUNT if impl == "popcount" else BINARY_MXU
     return gemm(body, (x_packed,), (w_packed,), w_scale, a_scale,

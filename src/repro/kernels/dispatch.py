@@ -64,7 +64,13 @@ from . import bgemm, i4gemm, i8gemm, pgemm, tgemm
 from . import harness
 from .harness import Tile  # re-export: the OperatingPoint tile override type
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run interpreted: exactly when the default
+    backend is not a TPU. The one place the repo decides it; kernels
+    called with interpret=None ask here on first trace, never at import."""
+    return jax.default_backend() != "tpu"
+
 
 #: Pallas kernels need M padded to the sublane multiple.
 PAD_M = 8
@@ -497,12 +503,10 @@ register(GemmCell(_op("none", "none", "*"), ("w",),
 # additionally shards over the "data" axis when it divides.
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """The repo's one version-tolerant shard_map (optim.compress owns it),
-    with replication checking off: Pallas calls inside the body have no
-    replication rule on older jax."""
-    from repro.optim.compress import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
+    """shard_map with varying-manual-axes checking off: the bodies mix
+    replicated prep with per-shard Pallas calls, which carry no vma rule."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -658,8 +662,7 @@ def _ep_row(cell, p, x, spec, op, ep):
                 x_ops = tuple(jnp.pad(v, ((0, 0), (0, padm), (0, 0)))
                               for v in x_ops)
             acc = harness.gemm_grouped(cell.body, x_ops, w_loc,
-                                       k=k, tile=tile, out="acc",
-                                       interpret=INTERPRET)[:, :m]
+                                       k=k, tile=tile, out="acc")[:, :m]
         else:
             acc = jax.vmap(lambda xo, wl: cell.acc(xo, wl, k))(x_ops, w_loc)
 
@@ -694,7 +697,7 @@ def _ep_row(cell, p, x, spec, op, ep):
         rqn = lambda a, ws, b=None: _requant_narrow(a, ws, b)
         y = (jax.vmap(rqn)(acc, w_scale, bias) if bias is not None
              else jax.vmap(rqn)(acc, w_scale))
-    return y.astype(jnp.bfloat16).reshape(*lead, n)
+    return round_to(y).reshape(*lead, n)
 
 
 def tp_plan(cell: GemmCell, spec, parallel: str, tp: TPSpec | None) -> str | None:
@@ -827,8 +830,7 @@ def _tp_row(cell, p, x, spec, op, tp):
                 if padm:
                     xl = tuple(jnp.pad(v, ((0, padm), (0, 0))) for v in xl)
                 acc = harness.gemm(cell.body, xl, wl, None, None, None,
-                                   k=k_loc, tile=tile, out="acc",
-                                   interpret=INTERPRET)[:mm]
+                                   k=k_loc, tile=tile, out="acc")[:mm]
             else:
                 acc = cell.acc(xl, wl, k_loc)
             return acc, a_scale
@@ -853,12 +855,24 @@ def _tp_row(cell, p, x, spec, op, tp):
              else jax.vmap(rq)(acc, w_scale, a_scale))
     else:
         y = harness.requant(acc, w_scale, a_scale, bias)
-    return y.astype(jnp.bfloat16).reshape(*lead, n)
+    return round_to(y).reshape(*lead, n)
 
 
 # ---------------------------------------------------------------------------
 # the entry point
 # ---------------------------------------------------------------------------
+
+def round_to(y, dtype=jnp.bfloat16):
+    """`y` cast to `dtype`, rounded to bf16 where a Pallas kernel's output
+    rounds. A plain cast is not enough in XLA code: with excess precision
+    allowed (XLA's default) the TPU compiler drops an f32→bf16→f32 round
+    trip into the next elementwise op, so XLA code would hand its consumer
+    unrounded values and drift from the kernels. reduce_precision is never
+    dropped."""
+    if y.dtype == jnp.float32 and dtype == jnp.bfloat16:
+        y = jax.lax.reduce_precision(y, exponent_bits=8, mantissa_bits=7)
+    return y.astype(dtype)
+
 
 def _requant_narrow(acc, w_scale, bias):
     """Weight-only epilogue: scale in the accumulator dtype (bf16 TP wire),
@@ -946,14 +960,14 @@ def qgemm(p: dict, x: jnp.ndarray, spec, op: OperatingPoint | None = None, *,
             x_ops = tuple(jnp.pad(xo, ((0, padm), (0, 0))) for xo in x_ops)
             a_scale = jnp.pad(a_scale, (0, padm))
         y = harness.gemm(cell.body, x_ops, w_ops, w_scale, a_scale, bias,
-                         k=k, tile=_resolve_tile(op), interpret=INTERPRET)[:m]
+                         k=k, tile=_resolve_tile(op))[:m]
     else:
         acc = cell.acc(x_ops, w_ops, k)
         if cell.wide:
             y = harness.requant(acc, w_scale, a_scale, bias)
         else:
             y = _requant_narrow(acc, w_scale, bias)
-    return y.astype(jnp.bfloat16).reshape(*lead, n)
+    return round_to(y).reshape(*lead, n)
 
 
 # ---------------------------------------------------------------------------

@@ -67,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, bq: int = 256, bk: int = 256,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """q: (BH, Tq, dh); k, v: (BHk, Tk, dh); BH % BHk == 0 (GQA groups).
 
     Returns (BH, Tq, dh) in q's dtype. Block sizes clamp to the problem and
@@ -82,6 +82,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     assert tq % bq == 0 and tk % bk == 0, (tq, tk, bq, bk)
     grid = (bh, tq // bq, tk // bk)
     scale = 1.0 / dh ** 0.5
+    if interpret is None:
+        from .dispatch import pallas_interpret
+        interpret = pallas_interpret()
 
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,

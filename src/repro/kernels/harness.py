@@ -82,6 +82,8 @@ class MacBody:
     default_bkq: int = 16
     xk_per_q: int | None = None  # activation storage density (None = k_per_q)
     wk_per_q: int | None = None  # weight storage density (None = k_per_q)
+    w_ref: bool = False      # step gets the weight refs, not loaded tiles
+                             # (it loads slices itself, e.g. one plane)
     w_stack: int = 0         # >0: weight operands carry a leading stacked
                              # plane axis — (planes, N, Kq) — swept whole per
                              # grid step (plane-composed cells). The value is
@@ -103,15 +105,17 @@ def requant(dot, w_scale, a_scale, bias):
 
     out = dot * w_scale[n] * a_scale[m] + bias[n], computed in f32 so the
     wide accumulator never round-trips through a narrow dtype (§IV-B). Any
-    scale/bias may be None (identity). Callers cast the result themselves.
+    scale/bias may be None (identity). w_scale/bias are (N,) or (1, N) and
+    a_scale (M,) or (M, 1) — the kernel reads 2-D tiles, the jnp backend
+    1-D vectors; the arithmetic is the same. Callers cast the result.
     """
     y = dot.astype(jnp.float32)
     if w_scale is not None:
-        y = y * w_scale[None, :]
+        y = y * w_scale.reshape(1, -1)
     if a_scale is not None:
-        y = y * a_scale[:, None]
+        y = y * a_scale.reshape(-1, 1)
     if bias is not None:
-        y = y + bias[None, :]
+        y = y + bias.reshape(1, -1)
     return y
 
 
@@ -119,7 +123,8 @@ def _kernel(*refs, body: MacBody, k_total: int, bkq: int, acc_only: bool):
     """One (bm, bn) output tile; grid dim 2 sweeps Kq (output-stationary)."""
     nx, nw = body.n_x, body.n_w
     x_tiles = tuple(refs[i][...] for i in range(nx))
-    w_tiles = tuple(refs[nx + i][...] for i in range(nw))
+    w_tiles = tuple(refs[nx + i] if body.w_ref else refs[nx + i][...]
+                    for i in range(nw))
     ws_ref, as_ref, b_ref = refs[nx + nw:nx + nw + 3]
     o_ref = refs[nx + nw + 3]
     acc_refs = refs[nx + nw + 4:]
@@ -147,6 +152,35 @@ def _kernel(*refs, body: MacBody, k_total: int, bkq: int, acc_only: bool):
             o_ref[...] = y.astype(o_ref.dtype)
 
 
+#: TPU vreg tiling: a block's last dim must be a multiple of LANE (or the
+#: whole array dim), its second-to-last a multiple of SUBLANE (or whole).
+LANE, SUBLANE = 128, 8
+
+#: scoped-VMEM limit for the qgemm kernels. Mosaic's 16 MiB default is too
+#: small for the unpacking bodies: a packed K block is at least 128 words
+#: (4,096 elements) unless it is the whole K, and its unpacked (bn, 4096)
+#: operand tiles alone take several MiB. v5e has 128 MiB of VMEM per core.
+VMEM_LIMIT = 64 * 2 ** 20
+
+
+def k_block(body: MacBody, requested: int, units: int) -> int:
+    """The K block (grid quanta per step) for `body` over `units` quanta.
+
+    A divisor of `units` whose storage width on BOTH operand sides
+    (bkq*k_per_q/xk and bkq*k_per_q/wk) is a lane multiple, or the whole K —
+    the only K blocks Mosaic accepts. The largest such block <= requested,
+    else the smallest one above it (the whole K always qualifies)."""
+    q = body.k_per_q
+
+    def ok(b):
+        return b == units or all((b * q // side) % LANE == 0
+                                 for side in (body.xk, body.wk))
+
+    fits = [b for b in range(1, units + 1) if units % b == 0 and ok(b)]
+    below = [b for b in fits if b <= requested]
+    return max(below) if below else min(fits)
+
+
 def fit_block(requested: int, dim: int, align: int = 1) -> int:
     """Largest block <= requested that divides dim exactly, preferring
     multiples of `align` (TPU sublane alignment for the M block — an
@@ -168,7 +202,7 @@ def gemm(body: MacBody, x_ops: Sequence[jnp.ndarray], w_ops: Sequence[jnp.ndarra
          w_scale: jnp.ndarray, a_scale: jnp.ndarray,
          bias: jnp.ndarray | None = None, *, k: int,
          tile: Tile | None = None,
-         interpret: bool = True, out: str = "requant") -> jnp.ndarray:
+         interpret: bool | None = None, out: str = "requant") -> jnp.ndarray:
     """Run `body` through the shared output-stationary skeleton.
 
     x_ops: n_x arrays (M, K/xk_per_q); w_ops: n_w arrays (N, K/wk_per_q)
@@ -185,9 +219,10 @@ def gemm(body: MacBody, x_ops: Sequence[jnp.ndarray], w_ops: Sequence[jnp.ndarra
     K shard and psums the integer partials across the model axis before the
     (deferred, out-of-kernel) requant. w_scale/a_scale/bias may then be None.
 
-    Block sizes are clamped to the largest divisor of each dim; callers
-    (`dispatch.qgemm`) handle M padding. interpret=True on CPU (validation),
-    False on real TPU.
+    bm is the largest sublane-aligned divisor of M (callers,
+    `dispatch.qgemm`, pad M); bn is a lane multiple over a cdiv grid (ragged
+    N needs no padding); the K block comes from `k_block`. interpret=None
+    follows the platform (`dispatch.pallas_interpret`).
     """
     if out not in ("requant", "acc"):
         raise ValueError(f"out={out!r}")
@@ -210,10 +245,14 @@ def gemm(body: MacBody, x_ops: Sequence[jnp.ndarray], w_ops: Sequence[jnp.ndarra
         else:
             assert wo.shape == ((n, k // wk) if not body.w_kmajor
                                 else (k // wk, n)), (wo.shape, n, k, wk)
-    bm = fit_block(tile.bm, m, align=8)
-    bn = fit_block(tile.bn, n)
-    bkq = fit_block(tile.bkq if tile.bkq is not None else body.default_bkq,
-                    units)
+    bm = fit_block(tile.bm, m, align=SUBLANE)
+    # N rides a cdiv grid: a lane-multiple bn (or the whole N) keeps every
+    # block Mosaic-aligned at any N (vocab 32,064 is not a multiple of 128).
+    # The edge block's out-of-range weight columns only feed output columns
+    # that the masked store drops, so no weight padding/copy is needed.
+    bn = n if n <= tile.bn else max(LANE, tile.bn // LANE * LANE)
+    bkq = k_block(body, tile.bkq if tile.bkq is not None else body.default_bkq,
+                  units)
     bx, bw = bkq * q // xk, bkq * q // wk   # per-side block widths (units)
     if out == "acc":
         # scales are unused by the raw-accumulator epilogue; feed dummies so
@@ -235,28 +274,31 @@ def gemm(body: MacBody, x_ops: Sequence[jnp.ndarray], w_ops: Sequence[jnp.ndarra
         w_spec = pl.BlockSpec((bw, bn), lambda i, j, kk: (kk, j))
     else:
         w_spec = pl.BlockSpec((bn, bw), lambda i, j, kk: (j, kk))
-    grid = (m // bm, n // bn, units // bkq)
+    row_n = pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))   # w_scale, bias
+    col_m = pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0))   # a_scale
+    grid = (m // bm, pl.cdiv(n, bn), units // bkq)
     out_dtype = jnp.int32 if out == "acc" else jnp.bfloat16
+    if interpret is None:
+        from .dispatch import pallas_interpret
+        interpret = pallas_interpret()
     return pl.pallas_call(
         functools.partial(_kernel, body=body, k_total=k, bkq=bkq,
                           acc_only=(out == "acc")),
         grid=grid,
-        in_specs=(
-            [x_spec] * body.n_x + [w_spec] * body.n_w + [
-                pl.BlockSpec((bn,), lambda i, j, kk: (j,)),   # w_scale
-                pl.BlockSpec((bm,), lambda i, j, kk: (i,)),   # a_scale
-                pl.BlockSpec((bn,), lambda i, j, kk: (j,)),   # bias
-            ]),
+        in_specs=[x_spec] * body.n_x + [w_spec] * body.n_w + [
+            row_n, col_m, row_n],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)] * body.n_acc,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*x_ops, *w_ops, w_scale, a_scale, bias)
+    )(*x_ops, *w_ops, w_scale.reshape(1, n), a_scale.reshape(m, 1),
+      bias.reshape(1, n))
 
 
 def gemm_grouped(body: MacBody, x_ops, w_ops, w_scale=None, a_scale=None,
                  bias=None, *, k: int, tile: Tile | None = None,
-                 interpret: bool = True, out: str = "requant"):
+                 interpret: bool | None = None, out: str = "requant"):
     """Grouped-expert entry point: `gemm` vmapped over a leading group axis.
 
     Every operand (each x_op, each w_op, and any non-None scale/bias)

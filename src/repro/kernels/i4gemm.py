@@ -39,7 +39,7 @@ INT4_W_I8A = MacBody("i4gemm_w4a8", n_x=1, n_w=1, n_acc=1,
 def i4gemm(x_q: jnp.ndarray, w_q4: jnp.ndarray, w_scale: jnp.ndarray,
            a_scale: jnp.ndarray, bias: jnp.ndarray | None = None, *,
            k: int, bm: int = 128, bn: int = 128, bkw: int = 64,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: bool | None = None) -> jnp.ndarray:
     """(M, K)i8 × (N, K/8)u32 nibble words → (M, N) bf16, fused requant."""
     return gemm(INT4_W_I8A, (x_q,), (w_q4,), w_scale, a_scale, bias,
                 k=k, tile=Tile(bm, bn, bkw), interpret=interpret)

@@ -29,7 +29,7 @@ I8_DOT = MacBody("i8gemm", n_x=1, n_w=1, n_acc=1, k_per_q=1,
 def i8gemm(x_q: jnp.ndarray, w_q: jnp.ndarray, w_scale: jnp.ndarray,
            a_scale: jnp.ndarray, bias: jnp.ndarray | None = None, *,
            bm: int = 128, bn: int = 128, bk: int = 512,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: bool | None = None) -> jnp.ndarray:
     """(M, K)i8 × (K, N)i8 → (M, N) bf16 with fused requant epilogue."""
     return gemm(I8_DOT, (x_q,), (w_q,), w_scale, a_scale, bias,
                 k=x_q.shape[1], tile=Tile(bm, bn, bk), interpret=interpret)

@@ -12,26 +12,33 @@ decode:
 
   grid (slot, kv-page-block), page-block innermost (output-stationary in the
   slot). The page table and per-slot positions ride in as scalar-prefetch
-  operands; each active step walks `pages[b, j*bkp : (j+1)*bkp]` and DMAs
-  those pages' K/V tiles from the pool (left in ANY/HBM memory space) into
-  VMEM scratch, dequantizes in-register (`_kv_quant`/`_kv_dequant` algebra:
-  int8 codes at the static KV scale, passthrough otherwise), and folds the
-  tile into the online-softmax carries (m/l/acc in VMEM scratch — the
+  operands; the K and V pools each enter as `bkp` page-sized BlockSpec
+  operands whose index maps read `pages[b, j*bkp + i]`, so the Pallas
+  pipeline DMAs exactly the table's pages into VMEM. A page block is the
+  whole (page_size, Hk, dh) trailing extent, which Mosaic accepts at any
+  head_dim (a hand-issued DMA of a head_dim-96 slice is refused). Each
+  page is dequantized in-register (`_kv_quant`/`_kv_dequant` algebra: int8
+  codes at the static KV scale, passthrough otherwise) and folded into the
+  online-softmax carries (m/l/acc in VMEM scratch — the
   `flash_attn._flash_kernel` structure: init on the first block, epilogue
-  `acc / max(l, eps)` on the last). GQA is a reshape: query heads (Hk, G, dh)
-  contract against the Hk kv heads of the tile.
+  `acc / max(l, eps)` on the last). GQA: query heads arrive grouped
+  (Hk, G, dh) and contract against the Hk kv heads of the page, both
+  einsums accumulating in f32.
 
-Early bound: per-slot `pos` gates each block with `pl.when(start <= pos)` —
-short slots stop READING at their last active page; only the (cheap) grid
-iteration continues to max_pages, and unallocated table entries inside an
-active block point at page 0 (the pool's scratch page) whose tokens the
-`tok <= pos` mask discards, exactly like the gather path.
+Early bound: each page's index map clamps its table column to the slot's
+last active page, `min(col, pos // page_size)`. Past it the block index
+repeats, so the pipeline issues no new DMA, and `pl.when(start <= pos)`
+skips the math — short slots stop READING at their last active page; only
+the (cheap) grid iteration continues to max_pages. Unallocated table
+entries at or before `pos` point at page 0 (the pool's scratch page) whose
+tokens the `tok <= pos` mask discards, exactly like the gather path.
 
 The tunable is `Tile.bkq` = pages per kv block (`bm`/`bn` are unused for
 this key), registered in the shipped TuneTable under the pseudo-cell key
 "paged_attn/decode/*" (kernel_bench --retune sweeps it). VMEM working set
-per step = 2 * bkp * page_size * Hk * dh operand bytes + the (Hq,)+(Hq,dh)
-f32 carries — `vmem_decode_tile_bytes` is the bench model.
+per step = 2 * bkp * page_size * Hk * dh operand bytes (double-buffered by
+the pipeline) + the (Hq,)+(Hq,dh) f32 carries — `vmem_decode_tile_bytes`
+is the bench model.
 
 CoW / prefix-sharing contract: identical to the gather path — the kernel
 only READS through `pages`; the scheduler forks shared pages before the
@@ -86,11 +93,11 @@ def vmem_decode_tile_bytes(page_size: int, hk: int, dh: int, hq: int,
             + (2 * hq + hq * dh) * 4)       # m, l, acc carries
 
 
-def _paged_decode_kernel(pages_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_scr, v_scr, m_ref, l_ref, acc_ref, sem, *,
-                         page_size, bkp, hk, scale, kv_int8, kv_scale):
+def _paged_decode_kernel(pages_ref, pos_ref, q_ref, *refs, page_size, bkp,
+                         scale, kv_int8, kv_scale):
+    k_refs, v_refs = refs[:bkp], refs[bkp:2 * bkp]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * bkp:]
     b, jb = pl.program_id(0), pl.program_id(1)
-    t = bkp * page_size
 
     @pl.when(jb == 0)
     def _init():
@@ -99,53 +106,39 @@ def _paged_decode_kernel(pages_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[b]
+    q = q_ref[0]                                       # (hk, g, dh)
+    for i in range(bkp):
+        start = (jb * bkp + i) * page_size
 
-    # early page-loop bound: a block whose first token is past the slot's
-    # write position holds no valid KV — skip both the DMAs and the math
-    @pl.when(jb * t <= pos)
-    def _step():
-        copies = []
-        for i in range(bkp):
-            pid = pages_ref[b, jb * bkp + i]
-            copies.append(pltpu.make_async_copy(
-                k_hbm.at[pid], k_scr.at[i], sem.at[0, i]))
-            copies.append(pltpu.make_async_copy(
-                v_hbm.at[pid], v_scr.at[i], sem.at[1, i]))
-        for cp in copies:
-            cp.start()
-        for cp in copies:
-            cp.wait()
+        # early page-loop bound: a page whose first token is past the
+        # slot's write position holds no valid KV — skip the math
+        @pl.when(start <= pos)
+        def _page(i=i, start=start):
+            k, v = k_refs[i][0], v_refs[i][0]          # (page_size, hk, dh)
+            if kv_int8:
+                # in-register dequant: the _kv_dequant algebra, static scale
+                k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
+                v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)
+            else:
+                k, v = k.astype(q.dtype), v.astype(q.dtype)
+            s = jnp.einsum("hgd,thd->hgt", q, k,
+                           preferred_element_type=jnp.float32) * scale
+            tok = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(tok <= pos, s, NEG_INF)
 
-        _, hq, dh = q_ref.shape
-        g = hq // hk
-        q = q_ref[0]                                   # (hq, dh)
-        k = k_scr[...].reshape(t, hk, dh)
-        v = v_scr[...].reshape(t, hk, dh)
-        if kv_int8:
-            # in-register dequant: the _kv_dequant algebra at the static scale
-            k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
-            v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)
-        else:
-            k, v = k.astype(q.dtype), v.astype(q.dtype)
-
-        qg = q.reshape(hk, g, dh)
-        s = jnp.einsum("hgd,thd->hgt", qg, k).astype(jnp.float32) * scale
-        s = s.reshape(hq, t)
-        tok = jb * t + jax.lax.broadcasted_iota(jnp.int32, (hq, t), 1)
-        s = jnp.where(tok <= pos, s, NEG_INF)
-
-        m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("hgt,thd->hgd", p.reshape(hk, g, t).astype(v.dtype), v)
-        acc_new = acc_prev * corr[:, None] + pv.reshape(hq, dh).astype(jnp.float32)
-        m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
+            m_prev, l_prev = m_ref[...], l_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+                "hgt,thd->hgd", p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
 
     @pl.when(jb == pl.num_programs(1) - 1)
     def _epilogue():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)[:, None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
                     ).astype(o_ref.dtype)
 
 
@@ -155,7 +148,7 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray, pages: jnp.ndarray,
                        pos: jnp.ndarray, *, pages_per_block: int | None = None,
                        kv_scale: float = 0.05,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """Single-token decode attention through the page-table indirection.
 
     q: (B, Hq, dh) compute dtype; k_pool/v_pool: (num_pages, page_size, Hk,
@@ -180,30 +173,39 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
     bkp = fit_block(pages_per_block, max_pages)
     grid = (b, max_pages // bkp)
     kv_int8 = k_pool.dtype == jnp.int8
+    if interpret is None:
+        from .dispatch import pallas_interpret
+        interpret = pallas_interpret()
+
+    # GQA: query heads grouped (Hk, G, dh) outside the kernel (a free XLA
+    # reshape) so the kernel never reshapes a head_dim-96 tile
+    g = hq // hk
+    blk = pl.BlockSpec((1, hk, g, dh), lambda bi, j, pages, pos: (bi, 0, 0, 0))
+
+    def page_spec(i):
+        def index_map(bi, j, pages, pos):
+            col = jnp.minimum(j * bkp + i, pos[bi] // page_size)
+            return pages[bi, col], 0, 0, 0
+        return pl.BlockSpec((1, page_size, hk, dh), index_map)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, hq, dh), lambda bi, j, pages, pos: (bi, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),     # V pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, hq, dh), lambda bi, j, pages, pos: (bi, 0, 0)),
+        in_specs=[blk] + [page_spec(i) for i in range(bkp)] * 2,
+        out_specs=blk,
         scratch_shapes=[
-            pltpu.VMEM((bkp, page_size, hk, dh), k_pool.dtype),
-            pltpu.VMEM((bkp, page_size, hk, dh), v_pool.dtype),
-            pltpu.VMEM((hq,), jnp.float32),           # m: running max
-            pltpu.VMEM((hq,), jnp.float32),           # l: running denominator
-            pltpu.VMEM((hq, dh), jnp.float32),        # acc: running output
-            pltpu.SemaphoreType.DMA((2, bkp)),
+            pltpu.VMEM((hk, g, 1), jnp.float32),      # m: running max
+            pltpu.VMEM((hk, g, 1), jnp.float32),      # l: running denominator
+            pltpu.VMEM((hk, g, dh), jnp.float32),     # acc: running output
         ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, page_size=page_size, bkp=bkp, hk=hk,
+        _paged_decode_kernel, page_size=page_size, bkp=bkp,
         scale=1.0 / dh ** 0.5, kv_int8=kv_int8, kv_scale=kv_scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
         interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)
+    )(pages.astype(jnp.int32), pos.astype(jnp.int32),
+      q.reshape(b, hk, g, dh), *[k_pool] * bkp, *[v_pool] * bkp)
+    return out.reshape(b, hq, dh)

@@ -38,16 +38,22 @@ from .harness import MacBody
 
 def _planes_step(xs, ws, accs, *, bkq, bits):
     k = bkq * pack.WORD
-    wp = ws[0]                                  # (P, bn, bkq) uint32 planes
+    wp = ws[0]                      # (P, bn, bkq) uint32 planes, still a ref
     x = xs[0]                                   # (bm, k) int8 act codes
-    acc = accs[0]
-    coeffs = pack.plane_coeffs(bits)            # python ints: static in trace
-    for i in range(wp.shape[0]):                # live depth, unrolled
-        bits_i = pack.unpack_bits(wp[i], k).astype(jnp.int8)   # (bn, k) {0,1}
+
+    def plane(i, acc):
+        # one plane per iteration, loaded from the ref: an unrolled plane
+        # loop multiplies the unpacked (bn, k) code the compiler must emit
+        bits_i = pack.unpack_bits_i32(wp[i], k).astype(jnp.int8)  # {0,1}
         dot = jax.lax.dot_general(x, bits_i, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.int32)
-        acc = acc + jnp.int32(coeffs[i]) * dot
-    return (acc,)
+        # pack.plane_coeffs(bits)[i]: -2^(b-1) for the sign plane, then
+        # 2^(b-1-i)
+        coeff = jnp.where(i == 0, -(1 << (bits - 1)),
+                          jnp.left_shift(1, bits - 1 - i))
+        return acc + coeff * dot
+
+    return (jax.lax.fori_loop(0, wp.shape[0], plane, accs[0]),)
 
 
 def _mk(bits: int, name: str) -> MacBody:
@@ -55,7 +61,8 @@ def _mk(bits: int, name: str) -> MacBody:
                    k_per_q=pack.WORD, xk_per_q=1, wk_per_q=pack.WORD,
                    step=functools.partial(_planes_step, bits=bits),
                    finish=lambda accs, k: accs[0],
-                   unpacks_i8=True, default_bkq=8, w_stack=bits)
+                   unpacks_i8=True, default_bkq=8, w_stack=bits,
+                   w_ref=True)
 
 
 PLANES_W4_I8A = _mk(4, "pgemm_w4a8_planes")

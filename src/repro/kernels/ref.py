@@ -8,8 +8,17 @@ the BrainTTA contract (DESIGN.md §6):
   requant(acc) = acc * w_scale[n] * a_scale[m]  (+ bias[n])        -> bf16
 
 Operand encodings match `repro.core.pack`.
+
+The oracles with a bias epilogue are jitted like the kernels they check:
+XLA:CPU contracts `acc * scale + bias` into one fused multiply-add inside a
+compiled fusion, while op-by-op eager dispatch rounds the product first, so
+an eager oracle differs from any compiled kernel by one f32 ulp (enough to
+flip a bf16 rounding). Compiling both sides makes the epilogue the same
+arithmetic.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +48,7 @@ def ternary_gemm_ref(x_mask, x_sign, w_mask, w_sign, k: int,
     return (acc * w_scale[None, :] * a_scale[:, None]).astype(jnp.bfloat16)
 
 
+@jax.jit
 def i8_gemm_ref(x_q: jnp.ndarray, w_q: jnp.ndarray,
                 w_scale: jnp.ndarray, a_scale: jnp.ndarray,
                 bias: jnp.ndarray | None = None) -> jnp.ndarray:
@@ -59,6 +69,7 @@ def binary_gemm_mxu_ref(x_packed, w_packed, k: int, w_scale, a_scale) -> jnp.nda
     return binary_gemm_ref(x_packed, w_packed, k, w_scale, a_scale)
 
 
+@functools.partial(jax.jit, static_argnames="k")
 def wt_i8a_gemm_ref(x_q, w_mask, w_sign, k: int, w_scale, a_scale,
                     bias=None) -> jnp.ndarray:
     """Mixed w-ternary × a-int8 oracle: int8 codes against unpacked trits.
@@ -75,6 +86,7 @@ def wt_i8a_gemm_ref(x_q, w_mask, w_sign, k: int, w_scale, a_scale,
     return y.astype(jnp.bfloat16)
 
 
+@functools.partial(jax.jit, static_argnames="k")
 def i4_gemm_ref(x_q, w_q4, k: int, w_scale, a_scale, bias=None) -> jnp.ndarray:
     """int4-weight (s4 nibble words) × int8-activation oracle.
 

@@ -28,20 +28,18 @@ WORD = 32
 
 def _popcount_step(xs, ws, accs, *, bkq):
     xm, xsg = xs                            # (bm, bkq) mask/sign planes
-    wm, wsg = ws                            # (bn, bkq)
-
-    def body(i, carry):
-        act, dis = carry
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)
-        xmi, xsi = sl(xm), sl(xsg)                    # (bm, 1)
-        wmi, wsi = sl(wm).T, sl(wsg).T                # (1, bn)
-        active = jnp.bitwise_and(xmi, wmi)            # (bm, bn)
-        disagree = jnp.bitwise_and(active, jnp.bitwise_xor(xsi, wsi))
+    wm, wsg = ws[0].T, ws[1].T              # (bkq, bn)
+    act, dis = accs
+    # one packed word per step, unrolled: static lane/sublane slices are what
+    # Mosaic lowers (a dynamic lane slice is not)
+    for i in range(bkq):
+        w = slice(i, i + 1)                 # word i: x column, w row
+        active = jnp.bitwise_and(xm[:, w], wm[w, :])          # (bm, bn)
+        disagree = jnp.bitwise_and(
+            active, jnp.bitwise_xor(xsg[:, w], wsg[w, :]))
         act = act + jax.lax.population_count(active).astype(jnp.int32)
         dis = dis + jax.lax.population_count(disagree).astype(jnp.int32)
-        return act, dis
-
-    return jax.lax.fori_loop(0, bkq, body, (accs[0], accs[1]))
+    return act, dis
 
 
 def _popcount_finish(accs, k_total):
@@ -88,7 +86,7 @@ TERNARY_W_I8A = MacBody("tgemm_wt_i8a", n_x=1, n_w=2, n_acc=1, k_per_q=WORD,
 
 def tgemm(x_mask, x_sign, w_mask, w_sign, w_scale, a_scale, *, k: int,
           bm: int = 128, bn: int = 128, bkw: int = 16,
-          impl: str = "popcount", interpret: bool = True) -> jnp.ndarray:
+          impl: str = "popcount", interpret: bool | None = None) -> jnp.ndarray:
     """Packed ternary GEMM: planes (M, K/32)u32 × (N, K/32)u32 → (M, N) bf16."""
     body = TERNARY_POPCOUNT if impl == "popcount" else TERNARY_MXU
     return gemm(body, (x_mask, x_sign), (w_mask, w_sign), w_scale, a_scale,

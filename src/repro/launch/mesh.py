@@ -12,12 +12,22 @@ inside the pod where ICI bandwidth lives.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Every mesh of the repo: `jax.make_mesh` with Auto axes. The repo's
+    sharding is GSPMD-style (with_sharding_constraint, shard_map specs,
+    NamedSharding placement); jax's default Explicit axes reject it."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n: int | None = None, *, model: int = 1):
@@ -25,7 +35,7 @@ def make_host_mesh(n: int | None = None, *, model: int = 1):
     devs = jax.devices()
     n = n or len(devs)
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

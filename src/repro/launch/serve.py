@@ -106,6 +106,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
 
 import jax
@@ -114,7 +115,9 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch import kv_cache
+from repro.launch import sharding as shardlib
 from repro.launch.kv_cache import NULL_PAGE, PageTable, pages_for
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.models.common import ModelCtx, sample_token
 
@@ -161,6 +164,56 @@ class _Plan:
     table: np.ndarray | None        # masked (phys_slots, max_pages), paged only
     chunk: dict | None              # chunk operands, see _plan_chunk
     will_retire: tuple = ()         # predicted retires excluded from `active`
+
+
+#: JAX's persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset:
+#: a fixed path in the checkout (the path is part of the cache key, so a
+#: directory that moves never hits). Listed in .gitignore.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compile cache at its one directory and return
+    it: JAX_COMPILATION_CACHE_DIR when set (JAX reads it itself, so nothing
+    is set here), else `COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.normpath(COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def serve_params(cfg, *, seed: int = 0, mesh=None, plane_twins: bool = False):
+    """Random weights from `seed` in the packed serve layout, made where
+    they will live: one jit draws and packs them
+    (`transformer.init_packed`, one scanned period at a time), so the
+    training layout never exists whole. Under `mesh` the program's
+    out_shardings are the serve sharding rules and each training-layout
+    piece is pinned to the same rules before packing: no device ever holds
+    the unsharded model.
+
+    Returns (packed params, bytes of the training layout)."""
+    key = jax.random.PRNGKey(seed)
+    constrain = lambda tree: tree
+    if mesh is not None:
+        constrain = lambda tree: jax.lax.with_sharding_constraint(
+            tree, shardlib.param_shardings(mesh, tree, fsdp=False))
+
+    def build(k):
+        return transformer.init_packed(k, cfg, plane_twins=plane_twins,
+                                       constrain=constrain)
+
+    out_shardings = None
+    if mesh is not None:
+        out_shardings = shardlib.param_shardings(
+            mesh, jax.eval_shape(build, key), fsdp=False)
+    sparams = jax.jit(build, out_shardings=out_shardings)(key)
+    train_shapes = jax.eval_shape(lambda k: transformer.init(k, cfg), key)
+    train_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(train_shapes))
+    return sparams, train_bytes
 
 
 def default_buckets(lo: int, hi: int) -> tuple[int, ...]:
@@ -362,7 +415,6 @@ class Server:
             # pack.shardable_words) and the cache per-data-shard (pool pages
             # / slab slots over "data"); non-dividing axes replicate. The
             # shard_map in qgemm then consumes the shards in place.
-            from repro.launch import sharding as shardlib
             self.params = jax.device_put(
                 self.params,
                 shardlib.param_shardings(mesh, self.params, fsdp=False))
@@ -661,7 +713,6 @@ class Server:
             self.cache = kv_cache.scatter_pages(self.cache, image, [page],
                                                 self.paged_mask)
             if self.mesh is not None:
-                from repro.launch import sharding as shardlib
                 self.cache = shardlib.repin_serve_cache(self.mesh, self.cache)
             self.stats["tier_hits_host" if tiername == "host"
                        else "tier_hits_disk"] += 1
@@ -1465,6 +1516,7 @@ def main(argv=None):
                          "chunk) exceed this — the CI recompile-regression "
                          "gate")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -1480,16 +1532,14 @@ def main(argv=None):
                 f"--mesh {args.mesh} needs {d * m} devices, have "
                 f"{len(jax.devices())} (set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={d * m} on CPU)")
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         print(f"mesh: data={d} x model={m} ({d * m} devices); "
               f"qgemm under shard_map, paged pool sharded over data")
 
-    params = transformer.init(jax.random.PRNGKey(0), cfg)
-    sparams = transformer.pack_for_serve(
-        params, cfg,
+    sparams, train_b = serve_params(
+        cfg, mesh=mesh,
         plane_twins=args.spec_draft is not None or args.impl == "planes")
-    train_b = sum(np.asarray(x).nbytes for x in jax.tree.leaves(params))
-    serve_b = sum(np.asarray(x).nbytes for x in jax.tree.leaves(sparams))
+    serve_b = sum(x.nbytes for x in jax.tree.leaves(sparams))
     print(f"packed weights: {train_b/2**20:.1f} MiB -> {serve_b/2**20:.1f} MiB "
           f"({train_b/serve_b:.1f}x smaller, policy={cfg.policy})")
 
@@ -1551,9 +1601,10 @@ def main(argv=None):
     dt = time.time() - t0
     total_new = sum(len(r.out) for r in srv.completed)
     layout = "paged" if args.paged else "contiguous"
+    dev = jax.devices()[0]
     print(f"served {len(srv.completed)} requests, {total_new} tokens, "
-          f"{ticks} ticks, {dt:.1f}s ({total_new/dt:.1f} tok/s on CPU, "
-          f"{layout} cache)")
+          f"{ticks} ticks, {dt:.1f}s on {dev.platform} ({dev.device_kind}), "
+          f"{layout} cache — wall time including compiles, not a benchmark")
     total_sigs = sum(srv.compile_counts.values())
     print(f"jit signatures: prefill={srv.compile_counts['prefill']} "
           f"(buckets={list(srv.buckets)}), decode={srv.compile_counts['decode']}, "

@@ -39,16 +39,9 @@ from repro.core import pack
 from .mesh import dp_axes
 
 def abstract_mesh(shape=(16, 16), axes=("data", "model")):
-    """An AbstractMesh for rule evaluation — no devices needed.
-
-    jax >= 0.4.36 constructs AbstractMesh from a ((name, size), ...) shape
-    tuple; older releases took (sizes, names) positionally. Accept the
-    legacy (sizes, names) call shape here and translate."""
+    """An AbstractMesh for rule evaluation — no devices needed."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:   # pre-0.4.36 signature
-        return AbstractMesh(tuple(shape), tuple(axes))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # path components that mark a row-parallel linear (contraction dim sharded)

@@ -131,10 +131,9 @@ def make_compressed_train_step(cfg: ArchConfig, sp, opt, mesh: Mesh, *,
         params = apply_updates(params, updates)
         return params, opt_state, {"loss": jnp.mean(losses), **om}
 
-    return compress.shard_map(body, mesh=mesh,
-                              in_specs=(P(), P(), P(tuple(dp)), P()),
-                              out_specs=(P(), P(), P()),
-                              check_vma=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(), P(tuple(dp)), P()),
+                         out_specs=(P(), P(), P()), check_vma=False)
 
 
 def make_prefill_step(cfg: ArchConfig, sp, *, ctx: ModelCtx | None = None):

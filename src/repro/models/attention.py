@@ -9,13 +9,16 @@ tiled (q_block × kv_block), which is mandatory at 32k+ context.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core.precision import PrecisionPolicy
+from repro.kernels.dispatch import pallas_interpret, round_to
 
 from . import common
 from .common import ModelCtx
@@ -37,6 +40,39 @@ def _kv_dequant(c, compute_dtype):
     if c.dtype == jnp.int8:
         return (c.astype(jnp.float32) * KV_SCALE).astype(compute_dtype)
     return c.astype(compute_dtype)
+
+
+def _per_head_shard(ctx: ModelCtx, cfg: ArchConfig, kernel, head_axes,
+                    out_axis):
+    """`kernel` as it runs under serve TP: XLA cannot partition a Mosaic
+    kernel, so it runs inside shard_map. When query and kv heads both split
+    evenly over the model axis each shard runs its own heads (GQA groups
+    stay whole per shard: both split over the same axis in the same order);
+    otherwise every shard runs all heads on replicated inputs.
+    head_axes: per argument, the axis holding heads (None = replicated);
+    out_axis: the output's head axis. Every other axis is replicated, so on
+    a mesh with data > 1 each shard gathers the whole KV pool and computes
+    every slot (docs/SERVING.md, Known constraints). Without a mesh the
+    kernel runs as is."""
+    if ctx.tp is None:
+        return kernel
+    ns = ctx.tp.size
+    split = cfg.n_heads % ns == 0 and cfg.n_kv_heads % ns == 0
+    ax = ctx.tp.axis if split else None
+
+    def spec(axis, ndim):
+        dims = [None] * ndim
+        if axis is not None:
+            dims[axis] = ax
+        return P(*dims)
+
+    def run(*args):
+        in_specs = tuple(spec(a, x.ndim) for a, x in zip(head_axes, args))
+        out_ndim = args[0].ndim
+        return jax.shard_map(kernel, mesh=ctx.tp.mesh, in_specs=in_specs,
+                             out_specs=spec(out_axis, out_ndim),
+                             check_vma=False)(*args)
+    return run
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +141,12 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
     shrinks the kv loop to the band — sub-quadratic in T.
     `q_offset`: absolute position of q[0] (prefill continuation / decode).
 
+    Scores and the PV products accumulate in f32, as in the flash and
+    paged-decode kernels: rounding them to bf16 first made this path drift
+    from the kernels by tens of percent in logits over a deep stack. The
+    softmax weights and the output round to bf16 where the flash kernel
+    rounds them (`dispatch.round_to`).
+
     Two schedules:
       cp=False  two-level scan (q blocks x kv blocks) — bounds the score temp
                 to (B, qb, H, kvb); used on host-scale runs and window layers.
@@ -149,7 +191,8 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
             kj = start_blk + j
             ks = jax.lax.dynamic_slice_in_dim(k, kj * kv_block, kv_block, axis=1)
             vs = jax.lax.dynamic_slice_in_dim(v, kj * kv_block, kv_block, axis=1)
-            s = jnp.einsum("bqhgd,bshd->bhgqs", qi_blk, ks).astype(jnp.float32) * scale
+            s = jnp.einsum("bqhgd,bshd->bhgqs", qi_blk, ks,
+                           preferred_element_type=jnp.float32) * scale
             k_pos = kj * kv_block + jnp.arange(kv_block)
             msk = jnp.ones((tq_, kv_block), bool)
             if causal:
@@ -162,7 +205,8 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1)
             acc_new = acc * corr[..., None] + jnp.einsum(
-                "bhgqs,bshd->bhgqd", p.astype(q.dtype), vs).astype(jnp.float32)
+                "bhgqs,bshd->bhgqd", round_to(p, q.dtype), vs,
+                preferred_element_type=jnp.float32)
             return (m_new, l_new, acc_new), None
 
         m0 = jnp.full((b, hk, g, tq_), NEG_INF, jnp.float32)
@@ -170,7 +214,7 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
         a0 = jnp.zeros((b, hk, g, tq_, dh), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(n_visit))
         o = acc / jnp.maximum(l, 1e-20)[..., None]
-        return o.astype(q.dtype)                     # (B, Hk, G, Tq', dh)
+        return round_to(o, q.dtype)                  # (B, Hk, G, Tq', dh)
 
     if cp:
         # single-level: whole (sequence-sharded) q against the kv sweep
@@ -228,14 +272,14 @@ def attn_apply(p, x, specs: AttnSpecs, cfg: ArchConfig, ctx: ModelCtx, *,
             and ctx.attn_cp is None):
         # TPU deployment path: fused flash-attention kernel (kernels/flash_attn)
         from repro.kernels.flash_attn import flash_attention as _flash
-        import os as _os
-        interp = _os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
         b_, t_, h_, dh_ = q.shape
         hk_ = k.shape[2]
         qf = jnp.moveaxis(q, 2, 1).reshape(b_ * h_, t_, dh_)
         kf = jnp.moveaxis(k, 2, 1).reshape(b_ * hk_, t_, dh_)
         vf = jnp.moveaxis(v, 2, 1).reshape(b_ * hk_, t_, dh_)
-        of = _flash(qf, kf, vf, causal=causal, interpret=interp)
+        flash = functools.partial(_flash, causal=causal,
+                                  interpret=pallas_interpret())
+        of = _per_head_shard(ctx, cfg, flash, (0, 0, 0), 0)(qf, kf, vf)
         o = jnp.moveaxis(of.reshape(b_, h_, t_, dh_), 1, 2)
         out = common.linear_apply(p["out"], o.reshape(b, t, -1), specs.out, ctx)
         if return_cache:
@@ -376,12 +420,13 @@ def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
             # fused page-walk kernel (reads the same post-fork table and the
             # post-write pool, so CoW/write semantics match the gather path)
             from repro.kernels import paged_attn as _pa
-            from repro.kernels.dispatch import INTERPRET as _interp
             h_, hk_, dh_ = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-            of = _pa.paged_flash_decode(
-                q[:, 0], k, v, pages, posb,
+            walk = functools.partial(
+                _pa.paged_flash_decode,
                 pages_per_block=_pa.resolve_pages_per_block(ctx.tune),
-                kv_scale=KV_SCALE, interpret=_interp)
+                kv_scale=KV_SCALE, interpret=pallas_interpret())
+            of = _per_head_shard(ctx, cfg, walk, (1, 2, 2, None, None), 1)(
+                q[:, 0], k, v, pages, posb)
             out = common.linear_apply(p["out"], of.reshape(b, 1, h_ * dh_),
                                       specs.out, ctx)
             return out, {"k": k, "v": v}

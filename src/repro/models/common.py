@@ -17,6 +17,7 @@ import numpy as np
 from repro.core import qlinear
 from repro.core.precision import LayerQuant, PrecisionPolicy
 from repro.core.qlinear import QLinearSpec
+from repro.kernels.dispatch import round_to
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +160,21 @@ def norm_init(d: int, kind: str = "rmsnorm", dtype=jnp.float32):
     if kind == "rmsnorm":
         return {"scale": jnp.ones((d,), dtype)}
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+
+def residual(x, y):
+    """The residual add x + y, in f32 and rounded to x's dtype explicitly
+    (`dispatch.round_to`). A bf16 add would leave the rounding to XLA,
+    which with excess precision keeps the unrounded sum wherever the add
+    fuses into its consumer: the values would then depend on fusion, which
+    differs between the Pallas and XLA backends."""
+    return round_to(x.astype(jnp.float32) + y.astype(jnp.float32), x.dtype)
+
+
+def gated(act, g, u, dtype):
+    """act(g) * u of a gated FFN, in f32 and rounded to `dtype` explicitly,
+    for the reason `residual` gives."""
+    return round_to(act(g.astype(jnp.float32)) * u.astype(jnp.float32), dtype)
 
 
 def norm_apply(p, x, kind: str = "rmsnorm", eps: float = 1e-6):

@@ -57,7 +57,7 @@ def ffn_apply(p, x, specs: FFNSpecs, ctx: ModelCtx):
     act = common.activation(specs.act)
     if specs.gated:
         g, u = jnp.split(h, 2, axis=-1)
-        h = act(g.astype(jnp.float32)).astype(x.dtype) * u
+        h = common.gated(act, g, u, x.dtype)
     else:
         h = act(h.astype(jnp.float32)).astype(x.dtype)
     return common.linear_apply(p["down"], h, specs.down, ctx)

@@ -15,25 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:                                   # jax >= 0.6: promoted to jax.shard_map
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-tolerant `shard_map`: newer jax renamed `check_rep` to
-    `check_vma` and moved the function out of `jax.experimental`. Every
-    caller in this repo (train step, tests) routes through here."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is not None:
-        try:
-            return _shard_map(f, **kwargs, check_vma=check_vma)
-        except TypeError:
-            return _shard_map(f, **kwargs, check_rep=check_vma)
-    return _shard_map(f, **kwargs)
-
-
 def _stochastic_round(x: jnp.ndarray, rng: jax.Array) -> jnp.ndarray:
     floor = jnp.floor(x)
     frac = x - floor

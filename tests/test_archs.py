@@ -159,6 +159,34 @@ def test_serve_packed_forward(arch, built):
     assert not bool(jnp.any(jnp.isnan(ld))), arch
 
 
+@pytest.mark.parametrize("arch,policy", [("llama3.2-3b", "int8"),
+                                         ("recurrentgemma-9b", "w4a8"),
+                                         ("deepseek-moe-16b", "mixed")])
+def test_init_packed_matches_pack_of_init(arch, policy):
+    """The period-at-a-time build equals pack_for_serve(init) up to the
+    last-ulp float differences of a differently compiled program: same
+    tree, shapes and dtypes; floats within a few ulp; codes on a rounding
+    boundary may flip, nothing else."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config(arch).reduced(), policy=policy,
+                              n_layers=5)
+    k = jax.random.PRNGKey(3)
+    got = transformer.init_packed(k, cfg, plane_twins=True)
+    want = transformer.pack_for_serve(transformer.init(k, cfg), cfg,
+                                      plane_twins=True)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == w.shape and g.dtype == w.dtype
+        if np.issubdtype(g.dtype, np.floating) or g.dtype == jnp.bfloat16:
+            ulp = 2.0 ** -7 if g.dtype == jnp.bfloat16 else 2.0 ** -20
+            np.testing.assert_allclose(g.astype(np.float32),
+                                       w.astype(np.float32), rtol=ulp,
+                                       atol=0)
+        else:
+            assert np.mean(g != w) < 1e-3
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_cache_shapes_match_init_cache(arch, built):
     cfg, sp, params = built(arch)

@@ -376,7 +376,13 @@ def test_random_trace_conserves_pages(seed):
                     pass
         elif op == 3 and busy:
             slot = list(busy)[int(rng.integers(len(busy)))]
-            pt.fork_cow(slot, int(rng.integers(busy[slot])))
+            try:
+                pt.fork_cow(slot, int(rng.integers(busy[slot])))
+            except RuntimeError:
+                # only a genuinely exhausted pool may refuse a fork: no free
+                # page and no parked page left to evict (the server reserves
+                # fork debt at admission; this raw trace does not)
+                assert not pt._free and not pt._cached
         _conserved(pt)
     for slot in list(busy):
         pt.retire(slot)

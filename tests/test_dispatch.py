@@ -166,6 +166,20 @@ def _quant_codes_x(p, x2d, spec):
     return None, None   # "none": bf16 activations, handled separately
 
 
+@jax.jit
+def _wide_epilogue(acc, ws, asc, bias):
+    """acc -> scales -> bias -> bf16, jitted like the kernels so that
+    XLA:CPU contracts the bias epilogue into one fused multiply-add on both
+    sides (see kernels/ref.py)."""
+    y = acc.astype(jnp.float32)
+    if ws is not None:
+        y = y * ws[None, :]
+    y = y * asc[:, None]
+    if bias is not None:
+        y = y + bias[None, :]
+    return y.astype(jnp.bfloat16)
+
+
 def _oracle(p, x2d, spec):
     """Dequantize-then-fp32 reference, factored so every float product is
     exact: integer-code dot (exact in f32 at these ranges) -> scales ->
@@ -174,14 +188,7 @@ def _oracle(p, x2d, spec):
     xq, asc = _quant_codes_x(p, x2d, spec)
     ws, bias = p.get("w_scale"), p.get("b")
     if xq is not None:                      # W&A cell: wide f32 requant
-        acc = xq @ wq.T
-        y = acc.astype(jnp.float32)
-        if ws is not None:
-            y = y * ws[None, :]
-        y = y * asc[:, None]
-        if bias is not None:
-            y = y + bias[None, :]
-        return y.astype(jnp.bfloat16)
+        return _wide_epilogue(xq @ wq.T, ws, asc, bias)
     # weight-only cell: bf16 accumulate, bf16 scale, f32 bias
     acc = x2d.astype(jnp.bfloat16) @ wq.astype(jnp.bfloat16).T
     y = acc if ws is None else acc * ws.astype(acc.dtype)

@@ -42,7 +42,8 @@ from repro.kernels import dispatch
 from repro.kernels.dispatch import OperatingPoint
 
 CELLS = sorted(dispatch.cells())
-MESHES = {ns: jax.make_mesh((8 // ns, ns), ("data", "model")) for ns in (2, 4)}
+from repro.launch.mesh import make_mesh
+MESHES = {ns: make_mesh((8 // ns, ns), ("data", "model")) for ns in (2, 4)}
 checked = [0]
 sharded_plans = [0]
 

@@ -76,9 +76,8 @@ def test_collective_bytes_counted_with_trips():
     def fn(x):
         return jax.lax.scan(body, x, None, length=4)[0]
 
-    from repro.optim.compress import shard_map
     sh = NamedSharding(mesh, P())
-    f = shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P())
+    f = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P())
     c = jax.jit(f, in_shardings=sh).lower(
         jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
     cost = hlo_cost.analyze_compiled(c)

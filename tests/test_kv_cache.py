@@ -148,7 +148,8 @@ def test_pool_device_sharded_over_data_host_table_global():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((ndev,), ("data",))
     page_size, slots, max_pages = 4, 3, 4
     num_pages = max(8, 4 * ndev)              # divides the data axis exactly
     pool = jnp.zeros((num_pages, page_size, 2, 8), jnp.float32)

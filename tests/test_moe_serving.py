@@ -45,7 +45,8 @@ from repro.core.quantize import QuantSpec
 from repro.kernels import dispatch
 from repro.kernels.dispatch import OperatingPoint
 
-MESH = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+MESH = make_mesh((2, 4), ("data", "model"))
 
 def build(wprec, aprec, bias, experts, k, parallel, seed=0):
     spec = qlinear.QLinearSpec(
@@ -106,7 +107,8 @@ from repro.launch.serve import Request, Server
 from repro.models import transformer
 from repro.models.common import ModelCtx
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 PROMPT_LENS, MAX_NEW, CACHE_LEN, PAGE_SIZE = (3, 9, 14), 4, 32, 4
 NUM_PAGES = 24
 rng = np.random.default_rng(7)

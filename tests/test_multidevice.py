@@ -19,7 +19,8 @@ from repro.models import registry, transformer
 from repro.models.common import ModelCtx
 from repro.optim.adamw import adamw
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("llama3.2-3b").reduced()
 sp = transformer.build_specs(cfg)
 opt = adamw(3e-3)

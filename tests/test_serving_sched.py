@@ -361,7 +361,8 @@ from repro.launch.serve import Request, Server
 from repro.models import transformer
 from repro.models.common import ModelCtx
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 CACHE_LEN, PAGE_SIZE = 32, 4
 # 10 total pages (incl. scratch page 0): even, so the pool's page axis
 # divides data=2 and really device-shards (an odd pool falls back to

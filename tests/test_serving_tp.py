@@ -35,7 +35,8 @@ from repro.models import transformer
 from repro.models.common import ModelCtx
 
 MODEL = __TP__                      # TP degree; data = 8 // MODEL
-mesh = jax.make_mesh((8 // MODEL, MODEL), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8 // MODEL, MODEL), ("data", "model"))
 
 # same traffic as tests/test_serving.py
 PROMPT_LENS, MAX_NEW, CACHE_LEN, PAGE_SIZE = (3, 9, 14), 4, 32, 4
@@ -111,7 +112,8 @@ from repro.models.common import ModelCtx
 # slots=3 over data=2: the non-dividing batch that the CPU SPMD partitioner
 # silently miscompiled (wrong tokens, no error) before the inert phys-slot
 # padding. 3 prompts so all three slots really co-run.
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 PROMPT_LENS, MAX_NEW, CACHE_LEN, PAGE_SIZE = (3, 9, 14), 4, 32, 4
 NUM_PAGES = 24
 rng = np.random.default_rng(7)

@@ -72,7 +72,6 @@ def test_q8_codec_roundtrip_error(seed, shape):
 def test_compressed_psum_matches_mean(tmp_path):
     """int8-compressed all-reduce ~= exact psum within quantization error."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.optim.compress import shard_map
     devs = jax.devices()
     mesh = Mesh(np.array(devs).reshape(1,), ("d",))
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (64, 64))}
@@ -80,7 +79,7 @@ def test_compressed_psum_matches_mean(tmp_path):
     def body(gg):
         return compress.compressed_psum(gg, "d", jax.random.PRNGKey(1))
 
-    out = shard_map(body, mesh=mesh, in_specs=(P("d"),), out_specs=P("d"))(
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P("d"),), out_specs=P("d"))(
         {"w": g["w"][None]})
     got = out["w"][0]
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127.0
