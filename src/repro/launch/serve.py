@@ -125,6 +125,14 @@ WAITING, PREFILLING, RUNNING, PREEMPTED = (
     "WAITING", "PREFILLING", "RUNNING", "PREEMPTED")
 
 
+def _span(name: str, **stats):
+    """A host span "serve.<name>" with integer or string `stats`, in the
+    profiler's trace on the device's clock (docs/SERVING.md, "Tracing a
+    live server"). While no profiler is tracing it records nothing and
+    formats none of `stats`."""
+    return jax.profiler.TraceAnnotation("serve." + name, **stats)
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -516,11 +524,15 @@ class Server:
         a call-site trace tally. A re-trace of a signature already seen
         (jit-cache eviction, jax.clear_caches) does not inflate the count,
         and a new signature slipping through a reused key always raises it —
-        what the --jit-budget gate actually wants to bound."""
+        what the --jit-budget gate actually wants to bound.
+
+        The program is named `serve_<key>`, so the device trace shows its
+        runs as the module `jit_serve_<key>`."""
         def traced(*args):
             self._signatures[key].add(self._abstract_sig(args))
             self.compile_counts[key] = len(self._signatures[key])
             return fn(*args)
+        traced.__name__ = traced.__qualname__ = f"serve_{key}"
         return jax.jit(traced)
 
     # -- request lifecycle -----------------------------------------------------
@@ -745,7 +757,9 @@ class Server:
             jnp.asarray([n - 1], jnp.int32), jnp.asarray(read)[None],
             jnp.asarray(write)[None], jnp.asarray([1], jnp.int32),
             jnp.asarray([0], jnp.int32)))
-        req.out.append(self._sample(req, np.asarray(c_logits)[0, 0]))
+        with _span("sync", what="chunk"):
+            row = np.asarray(c_logits)[0, 0]
+        req.out.append(self._sample(req, row))
         self.stats["prefill_skips"] += 1
 
     def _try_start(self, s: int) -> bool:
@@ -762,6 +776,14 @@ class Server:
                 return False   # FIFO: the head waits for pages; no jumping
         self.queue.pop(0)
         n = len(req.prompt)
+        with _span("admit", rid=req.rid, tokens=n, bucket=self._bucket(n)):
+            self._prefill_into(s, req, keys)
+        return True
+
+    def _prefill_into(self, s: int, req: Request, keys):
+        """Prefill the admitted `req` into slot s: map or claim its pages,
+        sample its first token, scatter its KV."""
+        n = len(req.prompt)
         if self.paged and keys is not None:
             self._count_device_hits(keys)
             ids, shared = self.pt.admit_shared(s, n, keys)
@@ -771,7 +793,7 @@ class Server:
                 # one chunk step over the shared pages, no prefill at all
                 self._skip_prefill(s, req, n)
                 self._finish_start(s, req, n)
-                return True
+                return
             # shared pages already hold this prefix's KV (and possibly a
             # co-owner's decode bytes past it) — never rescatter them
             scatter_ids = np.where(shared, NULL_PAGE, ids).astype(np.int32)
@@ -782,19 +804,21 @@ class Server:
         toks[0, :n] = req.prompt
         logits, rc = self._pop_moe(self._prefill(
             self.params, jnp.asarray(toks), jnp.asarray([n - 1], jnp.int32)))
-        req.out.append(self._sample(req, np.asarray(logits[0, -1])))
-        if self.paged:
-            pad = pages_for(bucket, self.page_size) - len(scatter_ids)
-            if pad:
-                scatter_ids = np.concatenate(
-                    [scatter_ids, np.full(pad, NULL_PAGE, np.int32)])
-            self.cache = kv_cache.scatter_prefill(
-                self.cache, rc, s, paged_mask=self.paged_mask,
-                page_ids=scatter_ids, page_size=self.page_size)
-        else:
-            self.cache = kv_cache.scatter_prefill(self.cache, rc, s)
+        with _span("sync", what="prefill"):
+            row = np.asarray(logits[0, -1])
+        req.out.append(self._sample(req, row))
+        with _span("scatter", rid=req.rid):
+            if self.paged:
+                pad = pages_for(bucket, self.page_size) - len(scatter_ids)
+                if pad:
+                    scatter_ids = np.concatenate(
+                        [scatter_ids, np.full(pad, NULL_PAGE, np.int32)])
+                self.cache = kv_cache.scatter_prefill(
+                    self.cache, rc, s, paged_mask=self.paged_mask,
+                    page_ids=scatter_ids, page_size=self.page_size)
+            else:
+                self.cache = kv_cache.scatter_prefill(self.cache, rc, s)
         self._finish_start(s, req, n)
-        return True
 
     def _finish_start(self, s: int, req: Request, n: int):
         req.state = RUNNING
@@ -845,20 +869,23 @@ class Server:
         n = len(req.prompt)
         shared = None
         lead = 0
-        if keys is not None:
-            self._count_device_hits(keys)
-            ids, shared = self.pt.admit_shared(s, n, keys, defer_index=True)
-            self.stats["shared_pages"] += int(shared.sum())
-            if shared.all() and self._skip_prefill_ok:
-                # fully resident (tier re-admission): no chunks to run at
-                # all — sample the first token and go straight to RUNNING
-                self._skip_prefill(s, req, n)
-                self._finish_start(s, req, n)
-                return True
-            while lead < len(shared) and shared[lead]:
-                lead += 1
-        else:
-            self.pt.admit(s, n)
+        # bucket 0: no whole-prompt prefill runs, the chunks carry it
+        with _span("admit", rid=req.rid, tokens=n, bucket=0):
+            if keys is not None:
+                self._count_device_hits(keys)
+                ids, shared = self.pt.admit_shared(s, n, keys,
+                                                   defer_index=True)
+                self.stats["shared_pages"] += int(shared.sum())
+                if shared.all() and self._skip_prefill_ok:
+                    # fully resident (tier re-admission): no chunks to run
+                    # at all — sample the first token and go to RUNNING
+                    self._skip_prefill(s, req, n)
+                    self._finish_start(s, req, n)
+                    return True
+                while lead < len(shared) and shared[lead]:
+                    lead += 1
+            else:
+                self.pt.admit(s, n)
         self._prefill_ctx[s] = {"keys": keys, "shared": shared, "n": n}
         req.state = PREFILLING
         self.slot_req[s] = req
@@ -897,10 +924,11 @@ class Server:
         # rejected-draft garbage and must not enter the swap image —
         # swap_in_slot scatters pages_for(pos) pages, a larger slab would
         # shape-mismatch. swap_out below still releases EVERY held page.
-        ids = self.pt.slot_pages(s)[: pages_for(int(self.slot_pos[s]),
-                                                self.page_size)]
-        data = kv_cache.swap_out_slot(self.cache, s, ids, self.paged_mask)
-        self.pt.swap_out(s)
+        with _span("preempt", rid=req.rid):
+            ids = self.pt.slot_pages(s)[: pages_for(int(self.slot_pos[s]),
+                                                    self.page_size)]
+            data = kv_cache.swap_out_slot(self.cache, s, ids, self.paged_mask)
+            self.pt.swap_out(s)
         self._swap[req.rid] = _SwapState(int(self.slot_pos[s]), data)
         req.state = PREEMPTED
         self.preempted.append(req)
@@ -957,15 +985,17 @@ class Server:
             # actually evicting, and give up until next tick if it falls short
             if not self._make_room(need, self._prio(req)):
                 return False
-        ids = self.pt.swap_in(s, cover)
-        # the saved slab covers pages_for(pos) pages; a boundary resume
-        # allocates one page beyond it, filled by the very next decode write
-        self.cache = kv_cache.swap_in_slot(
-            self.cache, st.data, s, ids[: pages_for(st.pos, self.page_size)],
-            self.paged_mask)
-        if self.mesh is not None:
-            from repro.launch import sharding as shardlib
-            self.cache = shardlib.repin_serve_cache(self.mesh, self.cache)
+        with _span("resume", rid=req.rid):
+            ids = self.pt.swap_in(s, cover)
+            # the saved slab covers pages_for(pos) pages; a boundary resume
+            # allocates one page beyond it, filled by the very next decode
+            # write
+            self.cache = kv_cache.swap_in_slot(
+                self.cache, st.data, s,
+                ids[: pages_for(st.pos, self.page_size)], self.paged_mask)
+            if self.mesh is not None:
+                from repro.launch import sharding as shardlib
+                self.cache = shardlib.repin_serve_cache(self.mesh, self.cache)
         self.preempted.remove(req)
         del self._swap[req.rid]
         req.state = RUNNING
@@ -1118,7 +1148,8 @@ class Server:
                 will_retire.append(s)
         skip = frozenset(will_retire)
         if self.paged:
-            self._prepare_pages(skip=skip)
+            with _span("pages"):
+                self._prepare_pages(skip=skip)
             # physical pool pressure (aliasing-aware: shared pages count
             # once) — what the slab layout would need is Σ per-slot coverage
             self.stats["peak_pages"] = max(
@@ -1173,7 +1204,8 @@ class Server:
             if r is not None and r.state == RUNNING:
                 keff[s] = max(1, min(self.spec_k, r.max_new - len(r.out),
                                      self.cache_len - 1 - int(self.slot_pos[s])))
-        self._prepare_pages(lookahead=keff)
+        with _span("pages"):
+            self._prepare_pages(lookahead=keff)
         active = [s for s in sorted(keff) if self.slot_req[s] is not None
                   and self.slot_req[s].state == RUNNING]
         self.stats["peak_pages"] = max(
@@ -1211,7 +1243,8 @@ class Server:
                 self._draft(self.params, dcache, jnp.asarray(tokens),
                             jnp.asarray(pos), jnp.asarray(dtab)),
                 count=False)   # verify re-routes these positions exactly
-            rows = np.asarray(dlogits[:, 0])
+            with _span("sync", what="draft"):
+                rows = np.asarray(dlogits[:, 0])
             for s in live:
                 r = reqs[s]
                 d = sample_token(rows[s], r.temperature, r.seed,
@@ -1233,7 +1266,8 @@ class Server:
         vlogits, self.cache = self._pop_moe(self._verify(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos0),
             jnp.asarray(table), jnp.asarray(table), jnp.asarray(nreal)))
-        vrows = np.asarray(vlogits)
+        with _span("sync", what="verify"):
+            vrows = np.asarray(vlogits)
         self.stats["spec_ticks"] += 1
         for s in active:
             r = reqs[s]
@@ -1256,8 +1290,9 @@ class Server:
             # exactly this position (garbage beyond is overwritten there)
             self.slot_pos[s] = base[s] + len(emitted)
         self._epoch += 1
-        self._retire()   # truncates at a mid-batch EOS before retiring
-        self._drain_moe()
+        with _span("retire"):
+            self._retire()   # truncates at a mid-batch EOS before retiring
+            self._drain_moe()
         return bool(any(r is not None for r in self.slot_req) or self.queue
                     or self.preempted)
 
@@ -1276,9 +1311,13 @@ class Server:
         The pre-decode retire pass inside _build_plan clears requests that
         are already complete at admission (max_new == 1, or a prompt that
         fills the cache) so they never reach the decode step with nowhere
-        left to write."""
+        left to write.
+
+        Each call is one `serve.step` host span holding the tick's phase
+        spans (docs/SERVING.md, "Tracing a live server")."""
         if self.spec:
-            return self._spec_step()
+            with _span("step", plan_hit=0):
+                return self._spec_step()
         plan = None
         if self._prepared is not None:
             if self._prepared.epoch == self._epoch:
@@ -1287,8 +1326,15 @@ class Server:
             else:
                 self.stats["fences"] += 1
             self._prepared = None
+        with _span("step", plan_hit=int(plan is not None)):
+            return self._tick(plan)
+
+    def _tick(self, plan: _Plan | None):
+        """`step` once the prepared plan is consumed (`plan`) or fenced
+        (None: build one now)."""
         if plan is None:
-            plan = self._build_plan()
+            with _span("plan", ahead=0):
+                plan = self._build_plan()
         active, chunk = plan.active, plan.chunk
         if not active and chunk is None:
             return bool(self.queue or self.preempted
@@ -1299,40 +1345,43 @@ class Server:
         # either order is token-exact; decode-first matches the sequential
         # oracle's schedule.
         logits = greedy = nxt_dev = None
-        if active:
-            tokens = np.zeros((self.phys_slots, 1), np.int32)
-            pos = np.zeros(self.phys_slots, np.int32)
-            for i, s in enumerate(active):
-                tokens[s, 0] = plan.reqs[i].out[-1]
-                pos[s] = self.slot_pos[s]
-            self.pos_trace.append(self.slot_pos[active].copy())
-            if self.paged:
-                logits, self.cache = self._pop_moe(self._decode(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(pos), jnp.asarray(plan.table)))
-            else:
-                logits, self.cache = self._pop_moe(self._decode(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(pos)))
-            greedy = not any(r.temperature > 0 for r in plan.reqs)
-            if greedy:
-                # argmax on device, transfer (slots,) ints — not the whole
-                # vocab matrix (np and jnp argmax both break ties to the
-                # lowest index, so this equals sample_token at temp 0)
-                nxt_dev = jnp.argmax(logits[:, 0], axis=-1)
         c_logits = None
         chunk_req = None
-        if chunk is not None:
-            cs = chunk["slot"]
-            chunk_req = self.slot_req[cs]
-            self.stats["chunk_ticks"] += 1
-            c_logits, self.cache = self._pop_moe(self._chunk(
-                self.params, self.cache, jnp.asarray(chunk["tokens"]),
-                jnp.asarray([chunk["pos0"]], jnp.int32),
-                jnp.asarray(chunk["read"])[None],
-                jnp.asarray(chunk["write"])[None],
-                jnp.asarray([chunk["nreal"]], jnp.int32),
-                jnp.asarray([chunk["last_idx"]], jnp.int32)))
+        with _span("dispatch", slots=len(active),
+                   chunk=int(chunk is not None)):
+            if active:
+                tokens = np.zeros((self.phys_slots, 1), np.int32)
+                pos = np.zeros(self.phys_slots, np.int32)
+                for i, s in enumerate(active):
+                    tokens[s, 0] = plan.reqs[i].out[-1]
+                    pos[s] = self.slot_pos[s]
+                self.pos_trace.append(self.slot_pos[active].copy())
+                if self.paged:
+                    logits, self.cache = self._pop_moe(self._decode(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(pos), jnp.asarray(plan.table)))
+                else:
+                    logits, self.cache = self._pop_moe(self._decode(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(pos)))
+                greedy = not any(r.temperature > 0 for r in plan.reqs)
+                if greedy:
+                    # argmax on device, transfer (slots,) ints — not the
+                    # whole vocab matrix (np and jnp argmax both break ties
+                    # to the lowest index, so this equals sample_token at
+                    # temp 0)
+                    nxt_dev = jnp.argmax(logits[:, 0], axis=-1)
+            if chunk is not None:
+                cs = chunk["slot"]
+                chunk_req = self.slot_req[cs]
+                self.stats["chunk_ticks"] += 1
+                c_logits, self.cache = self._pop_moe(self._chunk(
+                    self.params, self.cache, jnp.asarray(chunk["tokens"]),
+                    jnp.asarray([chunk["pos0"]], jnp.int32),
+                    jnp.asarray(chunk["read"])[None],
+                    jnp.asarray(chunk["write"])[None],
+                    jnp.asarray([chunk["nreal"]], jnp.int32),
+                    jnp.asarray([chunk["last_idx"]], jnp.int32)))
         # -- optimistic host advance (deterministic consequences of the
         # dispatch — token VALUES stay unknown until fix-up)
         for s in active:
@@ -1356,27 +1405,33 @@ class Server:
             pend = set(active)
             if chunk is not None and chunk["final"]:
                 pend.add(chunk["slot"])
-            self._prepared = self._build_plan(pending=frozenset(pend))
+            with _span("plan", ahead=1):
+                self._prepared = self._build_plan(pending=frozenset(pend))
         # -- fix-up: the device tokens land in the Request objects CAPTURED
         # at dispatch (plan.reqs) — a pending slot may have been preempted
         # (or its slot re-assigned) during the ahead build
         if active:
             if greedy:
-                nxt = np.asarray(nxt_dev)
+                with _span("sync", what="decode"):
+                    nxt = np.asarray(nxt_dev)
                 for i, s in enumerate(active):
                     self._deliver(plan.reqs[i], int(nxt[s]))
             else:
-                rows = np.asarray(logits[:, 0])        # (slots, V) to host
-                for i, s in enumerate(active):
-                    r = plan.reqs[i]
-                    self._deliver(r, self._sample(r, rows[s]))
+                with _span("sync", what="decode"):
+                    rows = np.asarray(logits[:, 0])    # (slots, V) to host
+                with _span("sample", rows=len(active)):
+                    for i, s in enumerate(active):
+                        r = plan.reqs[i]
+                        self._deliver(r, self._sample(r, rows[s]))
         if chunk is not None and chunk["final"]:
-            self._deliver(chunk_req,
-                          self._sample(chunk_req, np.asarray(c_logits)[0, 0]))
+            with _span("sync", what="chunk"):
+                row = np.asarray(c_logits)[0, 0]
+            self._deliver(chunk_req, self._sample(chunk_req, row))
         quiet = (frozenset(self._prepared.will_retire)
                  if self._prepared is not None else frozenset())
-        self._retire(quiet=quiet)
-        self._drain_moe()
+        with _span("retire"):
+            self._retire(quiet=quiet)
+            self._drain_moe()
         return bool(any(r is not None for r in self.slot_req) or self.queue
                     or self.preempted)
 
