@@ -40,6 +40,15 @@ per step = 2 * bkp * page_size * Hk * dh operand bytes (double-buffered by
 the pipeline) + the (Hq,)+(Hq,dh) f32 carries — `vmem_decode_tile_bytes`
 is the bench model.
 
+Stacked pools: the decode step's scanned layers carry their K and V pools
+as one (L, num_pages, page_size, Hk, dh) array each (models/transformer.py
+`decode_step`), and the kernel reads layer `layer` of it directly: the
+layer index is a third scalar-prefetch operand and the page index maps
+return (layer, page, 0, 0, 0). Slicing the layer out first would make XLA
+materialise the whole layer pool as the custom call's operand every layer.
+A 4-D pool is the one-layer case (layer 0 of a leading unit axis, a
+bitcast).
+
 CoW / prefix-sharing contract: identical to the gather path — the kernel
 only READS through `pages`; the scheduler forks shared pages before the
 decode write lands (launch/serve.py `_prepare_pages`), and this kernel runs
@@ -93,8 +102,9 @@ def vmem_decode_tile_bytes(page_size: int, hk: int, dh: int, hq: int,
             + (2 * hq + hq * dh) * 4)       # m, l, acc carries
 
 
-def _paged_decode_kernel(pages_ref, pos_ref, q_ref, *refs, page_size, bkp,
-                         scale, kv_int8, kv_scale):
+def _paged_decode_kernel(pages_ref, pos_ref, layer_ref, q_ref, *refs,
+                         page_size, bkp, scale, kv_int8, kv_scale):
+    del layer_ref                                      # read by the index maps
     k_refs, v_refs = refs[:bkp], refs[bkp:2 * bkp]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * bkp:]
     b, jb = pl.program_id(0), pl.program_id(1)
@@ -146,24 +156,30 @@ def _paged_decode_kernel(pages_ref, pos_ref, q_ref, *refs, page_size, bkp,
                                              "interpret"))
 def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray, pages: jnp.ndarray,
-                       pos: jnp.ndarray, *, pages_per_block: int | None = None,
+                       pos: jnp.ndarray, layer: jnp.ndarray | None = None, *,
+                       pages_per_block: int | None = None,
                        kv_scale: float = 0.05,
                        interpret: bool | None = None) -> jnp.ndarray:
     """Single-token decode attention through the page-table indirection.
 
     q: (B, Hq, dh) compute dtype; k_pool/v_pool: (num_pages, page_size, Hk,
-    dh) pool dtype (int8 codes at `kv_scale`, or the compute dtype); pages:
-    (B, max_pages) int32 page table (NULL/unallocated entries point at the
-    scratch page 0); pos: (B,) int32 per-slot positions — the new token's
-    KV must ALREADY be written at pages[b, pos[b]//P] offset pos[b]%P (the
-    caller owns the write, same as the gather path). Returns (B, Hq, dh).
+    dh) pool dtype (int8 codes at `kv_scale`, or the compute dtype), or a
+    stacked (L, num_pages, page_size, Hk, dh) pool read at scalar int32
+    `layer`; pages: (B, max_pages) int32 page table (NULL/unallocated
+    entries point at the scratch page 0); pos: (B,) int32 per-slot positions
+    — the new token's KV must ALREADY be written at pages[b, pos[b]//P]
+    offset pos[b]%P (the caller owns the write, same as the gather path).
+    Returns (B, Hq, dh).
 
     `pages_per_block` (Tile.bkq of the "paged_attn/decode/*" TuneTable
     entry; clamped to a divisor of max_pages) sets how many pages one grid
     step DMAs and folds into the online-softmax carries.
     """
+    if layer is None:
+        assert k_pool.ndim == 4, k_pool.shape
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     b, hq, dh = q.shape
-    num_pages, page_size, hk, dh_k = k_pool.shape
+    _, num_pages, page_size, hk, dh_k = k_pool.shape
     assert dh == dh_k and v_pool.shape == k_pool.shape
     assert hq % hk == 0, (hq, hk)
     max_pages = pages.shape[1]
@@ -180,16 +196,17 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
     # GQA: query heads grouped (Hk, G, dh) outside the kernel (a free XLA
     # reshape) so the kernel never reshapes a head_dim-96 tile
     g = hq // hk
-    blk = pl.BlockSpec((1, hk, g, dh), lambda bi, j, pages, pos: (bi, 0, 0, 0))
+    blk = pl.BlockSpec((1, hk, g, dh),
+                       lambda bi, j, pages, pos, layer: (bi, 0, 0, 0))
 
     def page_spec(i):
-        def index_map(bi, j, pages, pos):
+        def index_map(bi, j, pages, pos, layer):
             col = jnp.minimum(j * bkp + i, pos[bi] // page_size)
-            return pages[bi, col], 0, 0, 0
-        return pl.BlockSpec((1, page_size, hk, dh), index_map)
+            return layer[0], pages[bi, col], 0, 0, 0
+        return pl.BlockSpec((None, 1, page_size, hk, dh), index_map)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[blk] + [page_spec(i) for i in range(bkp)] * 2,
         out_specs=blk,
@@ -207,5 +224,6 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
         interpret=interpret,
     )(pages.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
       q.reshape(b, hk, g, dh), *[k_pool] * bkp, *[v_pool] * bkp)
     return out.reshape(b, hq, dh)

@@ -444,7 +444,7 @@ class Server:
                       "spec_accepted": 0, "spec_emitted": 0,
                       "admitted": 0, "prefill_skips": 0,
                       "tier_hits_device": 0, "tier_hits_host": 0,
-                      "tier_hits_disk": 0}
+                      "tier_hits_disk": 0, "decode_inplace_layers": 0}
         if cfg.n_experts:
             # moe_routed = total top-k assignments (kept + dropped);
             # moe_expert_tokens[e] = assignments expert e actually served
@@ -474,8 +474,8 @@ class Server:
                                 cache_len=self.cache_len, last_pos=lp))
         if paged:
             self._decode = self._counted("decode", lambda p, c, t, pos, pg:
-                transformer.decode_step(p, c, t, pos, self.sp, self.ctx,
-                                        pages=pg))
+                self._decode_step(p, c, t, pos, self.ctx, pages=pg),
+                donate_cache=True)
             # CoW byte copy: scalar (src, dst) page ids -> fixed signature,
             # so fork traffic compiles exactly once
             self._cow = self._counted("cow", lambda c, a, b:
@@ -493,8 +493,7 @@ class Server:
                 draft_ctx = dataclasses.replace(self.ctx, impl="planes",
                                                 draft_planes=self.spec_planes)
                 self._draft = self._counted("draft", lambda p, c, t, pos, pg:
-                    transformer.decode_step(p, c, t, pos, self.sp, draft_ctx,
-                                            pages=pg))
+                    self._decode_step(p, c, t, pos, draft_ctx, pages=pg))
                 self._verify = self._counted("verify",
                     lambda p, c, t, p0, rp, wp, nr:
                         transformer.decode_verify(p, c, t, p0, self.sp,
@@ -502,7 +501,17 @@ class Server:
                                                   write_pages=wp, nreal=nr))
         else:
             self._decode = self._counted("decode", lambda p, c, t, pos:
-                transformer.decode_step(p, c, t, pos, self.sp, self.ctx))
+                self._decode_step(p, c, t, pos, self.ctx), donate_cache=True)
+
+    def _decode_step(self, params, cache, tokens, pos, ctx, pages=None):
+        """`transformer.decode_step`, keeping in stats["decode_inplace_layers"]
+        how many layers of the traced program update the paged pool in
+        place (every full-attention layer of a paged server)."""
+        seen = {}
+        out = transformer.decode_step(params, cache, tokens, pos, self.sp,
+                                      ctx, pages=pages, trace_counts=seen)
+        self.stats["decode_inplace_layers"] = seen["inplace_layers"]
+        return out
 
     @staticmethod
     def _abstract_sig(args):
@@ -518,7 +527,7 @@ class Server:
             return ("static", repr(l))
         return (treedef, tuple(leaf_sig(l) for l in leaves))
 
-    def _counted(self, key: str, fn):
+    def _counted(self, key: str, fn, donate_cache: bool = False):
         """jit(fn) with signature-set accounting: compile_counts[key] is the
         number of DISTINCT abstract signatures ever traced under `key` — not
         a call-site trace tally. A re-trace of a signature already seen
@@ -527,13 +536,16 @@ class Server:
         what the --jit-budget gate actually wants to bound.
 
         The program is named `serve_<key>`, so the device trace shows its
-        runs as the module `jit_serve_<key>`."""
+        runs as the module `jit_serve_<key>`. `donate_cache` donates the
+        cache (argument 1): the output cache then aliases the input's
+        buffers, which the call invalidates — the caller rebinds
+        `self.cache` from the result."""
         def traced(*args):
             self._signatures[key].add(self._abstract_sig(args))
             self.compile_counts[key] = len(self._signatures[key])
             return fn(*args)
         traced.__name__ = traced.__qualname__ = f"serve_{key}"
-        return jax.jit(traced)
+        return jax.jit(traced, donate_argnums=(1,) if donate_cache else ())
 
     # -- request lifecycle -----------------------------------------------------
 

@@ -351,7 +351,7 @@ def init_cache_shapes(cfg: ArchConfig, batch: int, seq_len: int, window: int,
 
 
 def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
-                ctx: ModelCtx, *, window: int = 0, pages=None):
+                ctx: ModelCtx, *, window: int = 0, pages=None, layer=None):
     """One-token decode. x: (B, 1, D); pos: scalar or per-row (B,) int32.
 
     Per-row positions drive RoPE phases, the cache-write index, and the
@@ -368,6 +368,12 @@ def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
                   Unallocated table entries point at page 0 (scratch); reads
                   from it are masked by `valid`, writes to it are discarded
                   garbage by construction.
+      stacked     paged with `layer` (scalar int32): cache k/v are the
+                  (L, num_pages, page_size, Hk, dh) pools of every layer of
+                  a scan; the write lands at [layer, pid, off] and both read
+                  paths read that layer of the whole pool. Nothing slices
+                  the layer out, so under a scan that carries the pool the
+                  update is in place (transformer.decode_step).
 
     Prefix sharing contract: one physical page may appear in SEVERAL rows of
     `pages` (requests aliasing a common prompt prefix) — the gather-based
@@ -403,7 +409,7 @@ def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
     kq, vq = _kv_quant(k_new, cd)[:, 0], _kv_quant(v_new, cd)[:, 0]  # (B,Hk,dh)
     rows = jnp.arange(b)
     if pages is not None and not window:
-        page_size = cache["k"].shape[1]
+        page_size = cache["k"].shape[-3]
         if not isinstance(posb, jax.core.Tracer):
             # eager caller (oracle tests / benches): length-bound the table
             # to the last active page — dead pages past max(pos) are neither
@@ -412,8 +418,9 @@ def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
             pages = pages[:, :int(jnp.max(posb)) // page_size + 1]
         pid = pages[rows, posb // page_size]
         off = posb % page_size
-        k = cache["k"].at[pid, off].set(kq)
-        v = cache["v"].at[pid, off].set(vq)
+        at = (pid, off) if layer is None else (layer, pid, off)
+        k = cache["k"].at[at].set(kq)
+        v = cache["v"].at[at].set(vq)
         fused = (ctx.paged_attn == "fused"
                  or (ctx.paged_attn == "auto" and ctx.backend == "pallas"))
         if fused:
@@ -425,14 +432,20 @@ def attn_decode(p, x, cache, pos, specs: AttnSpecs, cfg: ArchConfig,
                 _pa.paged_flash_decode,
                 pages_per_block=_pa.resolve_pages_per_block(ctx.tune),
                 kv_scale=KV_SCALE, interpret=pallas_interpret())
-            of = _per_head_shard(ctx, cfg, walk, (1, 2, 2, None, None), 1)(
-                q[:, 0], k, v, pages, posb)
+            args = (q[:, 0], k, v, pages, posb)
+            if layer is not None:
+                args += (jnp.asarray(layer, jnp.int32),)
+            hax = k.ndim - 2                            # the pool's head axis
+            of = _per_head_shard(
+                ctx, cfg, walk, (1, hax, hax, None, None, None)[:len(args)],
+                1)(*args)
             out = common.linear_apply(p["out"], of.reshape(b, 1, h_ * dh_),
                                       specs.out, ctx)
             return out, {"k": k, "v": v}
         s = pages.shape[1] * page_size
-        kf = _kv_dequant(k[pages].reshape(b, s, *k.shape[2:]), x.dtype)
-        vf = _kv_dequant(v[pages].reshape(b, s, *v.shape[2:]), x.dtype)
+        at = pages if layer is None else (layer, pages)
+        kf = _kv_dequant(k[at].reshape(b, s, *k.shape[-2:]), x.dtype)
+        vf = _kv_dequant(v[at].reshape(b, s, *v.shape[-2:]), x.dtype)
         valid = jnp.arange(s)[None, :] <= posb[:, None]               # (B, S)
     else:
         s = cache["k"].shape[1]

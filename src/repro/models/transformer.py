@@ -240,9 +240,19 @@ def _recurrent_prefill(pm, h, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCtx):
     return jnp.moveaxis(ys, 0, 1), state
 
 
+def _pool_in_place(bs: BlockSpecs, cfg: ArchConfig, pages) -> bool:
+    """Whether decode updates this block's KV in place: its cache is the
+    paged pool of a full-attention layer (`attention.attn_decode`'s paged
+    case). Window rings, recurrent states, contiguous slabs and cross-KV
+    are not."""
+    return (pages is not None and bs.kind in ("attn", "local")
+            and not _mixer_window(cfg, bs.kind))
+
+
 def block_decode(p, x, cache, pos, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCtx,
-                 *, pages=None):
+                 *, pages=None, layer=None):
     """One-token decode through a block. x: (B,1,D); pos: scalar or (B,).
+    `layer`: index into stacked (L, ...) paged pools (`attn_decode`).
 
     Returns (x, cache, st) — st per `_moe_ffn`."""
     h = common.norm_apply(p["norm1"], x, cfg.norm)
@@ -250,7 +260,7 @@ def block_decode(p, x, cache, pos, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCt
         sub = {k: v for k, v in cache.items() if k in ("k", "v")}
         m, sub = attention.attn_decode(p["mixer"], h, sub, pos, bs.mixer, cfg, ctx,
                                        window=_mixer_window(cfg, bs.kind),
-                                       pages=pages)
+                                       pages=pages, layer=layer)
         cache = {**cache, **sub}
     elif bs.kind == "mlstm":
         m, cache2 = ssm.mlstm_decode(p["mixer"], h, cache, bs.mixer, ctx)
@@ -768,13 +778,23 @@ def decode_verify(params, cache, tokens, pos0, sp: ModelSpecs, ctx: ModelCtx, *,
 
 
 def decode_step(params, cache, tokens, pos, sp: ModelSpecs, ctx: ModelCtx, *,
-                pages=None):
+                pages=None, trace_counts: dict | None = None):
     """One decode step. tokens: (B, 1); pos: scalar int32 (aligned decode) or
     (B,) int32 — one position per slot (continuous batching).
 
     `pages`: (B, max_pages) int32 page table when the cache was built with
     `init_cache(..., paged=(num_pages, page_size))`; full-attention layers
     then write/read through the page lists (see launch/kv_cache.py).
+
+    In place: a scanned block whose cache is the paged pool
+    (`_pool_in_place`) carries its stacked (n_periods, num_pages, P, Hk, dh)
+    K and V through the layer scan, and each layer writes its token at
+    [layer, pid, off] and reads its layer of the whole pool. As scan
+    `xs`/`ys` each layer would slice its whole pool out and write it back
+    for one token. Every other cache (rings, recurrent state, slabs,
+    cross-KV) stays `xs`/`ys`. With the cache donated (the server's decode
+    program) the pool is never copied. `trace_counts`, when given, gets
+    "inplace_layers" at trace time: the layers updated that way.
 
     This is the `serve_step` the decode_* dry-run shapes lower. Under
     ctx.moe_stats, returns (logits, cache, moe_stats) — counters include the
@@ -788,18 +808,36 @@ def decode_step(params, cache, tokens, pos, sp: ModelSpecs, ctx: ModelCtx, *,
     x, new_cache["first"], st = block_decode(params["first"], x, cache["first"], pos,
                                              sp.first, cfg, ctx, pages=pages)
     moe_tot = _moe_add(moe_tot, st)
+    inplace = sum(_pool_in_place(bs, cfg, pages)
+                  for bs in (sp.first, *sp.rem, sp.last))
     if sp.n_periods:
+        carried = {f"b{t}" for t, bs in enumerate(sp.mid)
+                   if _pool_in_place(bs, cfg, pages)}
+        inplace += sp.n_periods * len(carried)
+        pools = {b: {n: cache["mid"][b][n] for n in ("k", "v")}
+                 for b in carried}
+        rest = {b: {n: leaf for n, leaf in c.items()
+                    if b not in carried or n not in ("k", "v")}
+                for b, c in cache["mid"].items()}
+
         def period(carry, scanned):
-            xx, tot = carry
-            pp, cc = scanned
-            ncs = {}
+            xx, tot, pools = carry
+            pp, cc, i = scanned
+            pools, ncs = dict(pools), {}
             for t, bs in enumerate(sp.mid):
-                xx, ncs[f"b{t}"], st = block_decode(pp[f"b{t}"], xx, cc[f"b{t}"],
-                                                    pos, bs, cfg, ctx, pages=pages)
+                b = f"b{t}"
+                xx, c, st = block_decode(
+                    pp[b], xx, {**cc[b], **pools.get(b, {})}, pos, bs, cfg,
+                    ctx, pages=pages, layer=i if b in carried else None)
+                if b in carried:
+                    pools[b] = {n: c.pop(n) for n in ("k", "v")}
+                ncs[b] = c
                 tot = _moe_add(tot, st)
-            return (xx, tot), ncs
-        (x, moe_tot), new_cache["mid"] = jax.lax.scan(
-            period, (x, moe_tot), (params["mid"], cache["mid"]))
+            return (xx, tot, pools), ncs
+        (x, moe_tot, pools), ys = jax.lax.scan(
+            period, (x, moe_tot, pools),
+            (params["mid"], rest, jnp.arange(sp.n_periods, dtype=jnp.int32)))
+        new_cache["mid"] = {b: {**c, **pools.get(b, {})} for b, c in ys.items()}
     for t, bs in enumerate(sp.rem):
         x, new_cache[f"rem{t}"], st = block_decode(params[f"rem{t}"], x,
                                                    cache[f"rem{t}"], pos, bs,
@@ -808,6 +846,8 @@ def decode_step(params, cache, tokens, pos, sp: ModelSpecs, ctx: ModelCtx, *,
     x, new_cache["last"], st = block_decode(params["last"], x, cache["last"], pos,
                                             sp.last, cfg, ctx, pages=pages)
     moe_tot = _moe_add(moe_tot, st)
+    if trace_counts is not None:
+        trace_counts["inplace_layers"] = inplace
     logits = _logits(params, x, sp, ctx)
     if ctx.moe_stats:
         return logits, new_cache, moe_tot

@@ -87,6 +87,29 @@ def test_kernel_matches_gather(b, max_pages, page_size, hk, hq, dh, int8):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("hk,hq", [(4, 4), (2, 4)], ids=["mha", "gqa"])
+def test_stacked_pool_reads_its_layer(layer, kv, hk, hq):
+    """A stacked (L, num_pages, P, Hk, dh) pool read at `layer` is the
+    4-D call on pool[layer], bit for bit: the layer index only moves the
+    page DMAs."""
+    b, max_pages, page_size, dh, n_layers = 3, 8, 4, 32, 5
+    int8 = kv == "int8"
+    dtype = jnp.float32 if int8 else jnp.bfloat16
+    q, _, _, pages, pos = _setup(7 + layer, b, max_pages, page_size, hk, hq,
+                                 dh, int8, dtype=dtype)
+    pools = [_setup(100 * i + layer, b, max_pages, page_size, hk, hq, dh,
+                    int8, dtype=dtype)[1:3] for i in range(n_layers)]
+    kst = jnp.stack([k for k, _ in pools])
+    vst = jnp.stack([v for _, v in pools])
+    got = paged_flash_decode(q, kst, vst, pages, pos, jnp.int32(layer),
+                             pages_per_block=4, kv_scale=KV_SCALE)
+    want = paged_flash_decode(q, kst[layer], vst[layer], pages, pos,
+                              pages_per_block=4, kv_scale=KV_SCALE)
+    assert jnp.array_equal(got, want)
+
+
 def test_block_size_invariance():
     q, kp, vp, pages, pos = _setup(11, 2, 8, 4, 2, 4, 32, False)
     outs = [paged_flash_decode(q, kp, vp, pages, pos, pages_per_block=bkp,
@@ -179,6 +202,31 @@ def test_attn_decode_fused_matches_gather(int8):
     assert jnp.array_equal(c_g["k"], c_f["k"])
     assert jnp.array_equal(c_g["v"], c_f["v"])
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_f), **TOL)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("int8", [False, True])
+def test_attn_decode_stacked_pool(layer, int8):
+    """attn_decode on a stacked pool at `layer`: the write lands in that
+    layer only, the same bytes the 4-D call writes to pool[layer]; the
+    gather path reads what it reads on pool[layer], and the fused path
+    agrees with it."""
+    x, cache, pos, pages = _paged_inputs(37, int8=int8)
+    others = _paged_inputs(41, int8=int8)[1]
+    stacked = {n: jnp.stack([others[n], cache[n], others[n]]) for n in "kv"}
+    layer_cache = {n: stacked[n][layer] for n in "kv"}
+    out_1, c_1 = attn_decode(PARAMS, x, layer_cache, pos, SPECS, CFG,
+                             CTX_GATHER, pages=pages)
+    out_g, c_g = attn_decode(PARAMS, x, stacked, pos, SPECS, CFG, CTX_GATHER,
+                             pages=pages, layer=jnp.int32(layer))
+    out_f, c_f = attn_decode(PARAMS, x, stacked, pos, SPECS, CFG, CTX_FUSED,
+                             pages=pages, layer=jnp.int32(layer))
+    for n in "kv":
+        want = stacked[n].at[layer].set(c_1[n])
+        assert jnp.array_equal(c_g[n], want)
+        assert jnp.array_equal(c_f[n], want)
+    assert jnp.array_equal(out_g, out_1)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_g), **TOL)
 
 
 def test_attn_decode_eager_length_bound(monkeypatch):

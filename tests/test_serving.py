@@ -12,6 +12,7 @@ per-row results).
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.launch.serve import Request, Server
+from repro.launch.serve import Request, Server, serve_params
 from repro.models import transformer
 from repro.models.common import ModelCtx
 
@@ -171,6 +172,81 @@ def test_jit_cache_discipline():
     assert srv.compile_counts["decode"] == 1, srv.compile_counts
     total = srv.compile_counts["prefill"] + srv.compile_counts["decode"]
     assert total <= len(srv.buckets) + 1
+
+
+def _pool_moves(hlo: str, layer_shape, stacked_shape) -> list[str]:
+    """Ops of a compiled program that move a whole layer pool: a
+    dynamic-slice whose result is one layer's pool (unit axes aside), or a
+    dynamic-update-slice whose result is the stacked pool."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\{[^}]*\} "
+                      r"(dynamic-slice|dynamic-update-slice)\(", line)
+        if m is None:
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(",") if d)
+        if (tuple(d for d in dims if d != 1) == layer_shape
+                or (m.group(2) == "dynamic-update-slice"
+                    and dims == stacked_shape)):
+            out.append(line.strip())
+    return out
+
+
+def test_decode_updates_pool_in_place(monkeypatch):
+    """The paged decode program updates the pool in place: its cache output
+    aliases its cache input (donated: the tick's input cache is deleted),
+    no op moves a whole layer pool, and every attention layer counts in
+    stats["decode_inplace_layers"]. After several ticks the cache holds bit
+    for bit what the layer scan with the pool as xs/ys writes (forced by
+    turning `_pool_in_place` off) on the same requests, gather path."""
+    cfg = dataclasses.replace(get_config("phi-3-vision-4.2b").reduced(),
+                              policy="mixed", n_layers=5)
+    params, _ = serve_params(cfg, seed=0)
+    ctx = ModelCtx(mode="serve", dtype=jnp.float32)
+    prompts = _prompts(cfg, lens=(5, 11), seed=13)
+
+    def serve():
+        srv = Server(cfg, params, slots=2, cache_len=CACHE_LEN,
+                     page_size=PAGE_SIZE, ctx=ctx)
+        for i, p in enumerate(prompts):
+            srv.submit(Request(i, p, 8))
+        for _ in range(5):
+            srv.step()
+        old, admitted = srv.cache, srv.stats["admitted"]
+        srv.step()                                  # a decode-only tick
+        assert srv.stats["admitted"] == admitted == len(prompts)
+        deleted = {l.is_deleted() for l in jax.tree.leaves(old)}
+        P, M = srv.phys_slots, srv.max_pages
+        z = lambda *s: jnp.zeros(s, jnp.int32)
+        hlo = srv._decode.lower(srv.params, srv.cache, z(P, 1), z(P),
+                                z(P, M)).compile().as_text()
+        return srv, deleted, hlo
+
+    srv, deleted, hlo = serve()
+    with monkeypatch.context() as m:
+        m.setattr(transformer, "_pool_in_place", lambda *a: False)
+        ref, _, ref_hlo = serve()
+
+    layer = srv.cache["first"]["k"].shape
+    stacked = srv.cache["mid"]["b0"]["k"].shape
+    assert stacked == (cfg.n_layers - 2,) + layer
+    assert srv.stats["decode_inplace_layers"] == cfg.n_layers
+    assert ref.stats["decode_inplace_layers"] == 0
+    assert deleted == {True}
+    n_params = len(jax.tree.leaves(srv.params))
+    n_cache = len(jax.tree.leaves(srv.cache))
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}, may-alias\)",
+                         hlo.split("\n", 1)[0])
+    assert sorted(map(int, aliased)) == list(range(n_params,
+                                                   n_params + n_cache))
+    assert not _pool_moves(hlo, layer, stacked)
+    assert _pool_moves(ref_hlo, layer, stacked)     # what the check catches
+    assert (jax.tree.structure(srv.cache)
+            == jax.tree.structure(ref.cache))
+    for a, b in zip(jax.tree.leaves(srv.cache), jax.tree.leaves(ref.cache)):
+        assert a.shape == b.shape and jnp.array_equal(a, b)
+    assert ([r.out for r in srv.slot_req]
+            == [r.out for r in ref.slot_req])
 
 
 def test_admission_is_metered_by_page_budget():

@@ -92,6 +92,54 @@ def test_paged_attn_compiles(one_chip, hq, hk, dh, kv_dtype):
     assert "tpu_custom_call" in text
 
 
+def test_decode_program_updates_pool_in_place(one_chip, monkeypatch):
+    """Phi-3's decode step at published widths, four layers (two scanned),
+    cache donated as `Server` donates it, compiled for the chip: the cache
+    output aliases its input, the paged kernel is there, and no slice,
+    update or copy has a layer's pool (or the stacked pools) as its result
+    — the scan carries the pool and each layer writes one token into it."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.launch.serve import serve_params
+    from repro.models import attention, transformer
+    from repro.models.common import ModelCtx
+
+    monkeypatch.setattr(dispatch, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(attention, "pallas_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("phi-3-vision-4.2b"), n_layers=4,
+                              policy="mixed")
+    sp = transformer.build_specs(cfg)
+    ctx = ModelCtx(mode="serve", backend="pallas")
+    slots, page, cache_len, num_pages = 8, 32, 3072, 257
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sd, jax.eval_shape(
+        lambda: serve_params(cfg, seed=0)[0]))
+    cache = jax.tree.map(sd, transformer.cache_shapes(
+        cfg, slots, cache_len, paged=(num_pages, page),
+        kv_dtype=jnp.bfloat16))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    text = jax.jit(
+        lambda p, c, t, pos, pg: transformer.decode_step(
+            p, c, t, pos, sp, ctx, pages=pg),
+        donate_argnums=(1,)).lower(
+            params, cache, i32(slots, 1), i32(slots),
+            i32(slots, cache_len // page)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "input_output_alias={" in text.split("\n", 1)[0]
+    pool = f"{num_pages},{page},{cfg.n_kv_heads},{cfg.head_dim}]"
+    moved = []
+    for line in text.splitlines():
+        m = re.search(r" = (.+?) ([a-z][a-z0-9-]*)\(", line)
+        if (m and pool in m.group(1) and m.group(2) in (
+                "dynamic-slice", "dynamic-update-slice", "copy",
+                "copy-start", "copy-done")):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+
+
 def test_flash_attn_compiles(one_chip):
     qkv = jax.ShapeDtypeStruct((32, 256, 96), jnp.bfloat16, sharding=one_chip)
     text = _compile_text(
