@@ -271,8 +271,8 @@ class Server:
                     self.ctx, ep=EPSpec(mesh=mesh, axis="model"))
         if cfg.n_experts:
             # routing telemetry: the serve entry points return a third
-            # {"expert_tokens", "dropped"} value; _pop_moe queues it and
-            # _drain_moe folds it into Server.stats AFTER the tick's fix-up
+            # {"expert_tokens", "dropped", "rows"} value; _pop_moe queues it
+            # and _drain_moe folds it into Server.stats AFTER the tick's fix-up
             # sync (converting at dispatch would sync the stream and kill
             # the dispatch-ahead overlap)
             self.ctx = dataclasses.replace(self.ctx, moe_stats=True)
@@ -447,8 +447,11 @@ class Server:
                       "tier_hits_disk": 0, "decode_inplace_layers": 0}
         if cfg.n_experts:
             # moe_routed = total top-k assignments (kept + dropped);
-            # moe_expert_tokens[e] = assignments expert e actually served
+            # moe_expert_tokens[e] = assignments expert e actually served;
+            # moe_expert_rows = rows the expert GEMMs computed (E x B*C per
+            # layer and call, capacity padding included)
             self.stats.update({"moe_routed": 0, "moe_dropped": 0,
+                               "moe_expert_rows": 0,
                                "moe_expert_tokens": [0] * cfg.n_experts})
         self._moe_pending: list = []
         # multi-tenant hooks (set by launch/multi_serve.MultiServer):
@@ -567,15 +570,19 @@ class Server:
         the END of a tick, after fix-up already synced the device stream —
         np.asarray here is free, while converting at dispatch would serialize
         dispatch-ahead."""
-        for st in self._moe_pending:
-            et = np.asarray(st["expert_tokens"])
-            dropped = int(np.asarray(st["dropped"]))
-            self.stats["moe_dropped"] += dropped
-            self.stats["moe_routed"] += int(et.sum()) + dropped
-            self.stats["moe_expert_tokens"] = [
-                a + int(b)
-                for a, b in zip(self.stats["moe_expert_tokens"], et)]
-        self._moe_pending.clear()
+        if not self._moe_pending:
+            return
+        with _span("moe_drain", calls=len(self._moe_pending)):
+            for st in self._moe_pending:
+                et = np.asarray(st["expert_tokens"])
+                dropped = int(np.asarray(st["dropped"]))
+                self.stats["moe_dropped"] += dropped
+                self.stats["moe_routed"] += int(et.sum()) + dropped
+                self.stats["moe_expert_rows"] += int(np.asarray(st["rows"]))
+                self.stats["moe_expert_tokens"] = [
+                    a + int(b)
+                    for a, b in zip(self.stats["moe_expert_tokens"], et)]
+            self._moe_pending.clear()
 
     def submit(self, req: Request):
         if len(req.prompt) > self.buckets[-1]:

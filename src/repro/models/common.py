@@ -59,11 +59,11 @@ class ModelCtx:
                                  # everywhere else.
     moe_stats: bool = False      # surface per-step MoE routing stats: the
                                  # top-level serve entry points return a third
-                                 # {"expert_tokens": (E,) i32, "dropped": i32}
-                                 # value summed over MoE blocks (Server.stats
-                                 # feeds on it). Off => 2-tuple returns, so
-                                 # non-MoE callers and lowering probes keep
-                                 # their shapes.
+                                 # {"expert_tokens": (E,) i32, "dropped": i32,
+                                 # "rows": i32} value summed over MoE blocks
+                                 # (Server.stats feeds on it). Off => 2-tuple
+                                 # returns, so non-MoE callers and lowering
+                                 # probes keep their shapes.
 
 
 TRAIN = ModelCtx(mode="train")

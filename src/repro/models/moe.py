@@ -99,44 +99,59 @@ def moe_apply(p, x, specs: MoESpecs, ctx: ModelCtx):
       "expert_tokens" — (E,) int32, tokens·top-k assignments that landed a
                         capacity slot on each expert this call (utilization)
       "dropped"       — int32, assignments past capacity (dropped this call)
+      "rows"          — int, rows the expert GEMMs compute this call
+                        (E · B·C, capacity padding included); static
     The counters are exact under EP because routing is replicated; the
     serve driver accumulates them into `Server.stats` when ctx.moe_stats.
+
+    The phases run under named scopes (moe_router, moe_dispatch,
+    moe_experts, moe_combine, moe_shared) that label their ops in the HLO
+    metadata and the device trace; they change no computation.
     """
     b, s, d = x.shape
     e, k = specs.n_experts, specs.top_k
     c = _capacity(s, specs)
 
-    logits = common.linear_apply(p["router"], x, specs.router, ctx).astype(jnp.float32)
-    gates = jax.nn.softmax(logits, axis=-1)                      # (B,S,E)
-    topv, topi = jax.lax.top_k(gates, k)                         # (B,S,K)
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        logits = common.linear_apply(p["router"], x, specs.router,
+                                     ctx).astype(jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)                  # (B,S,E)
+        topv, topi = jax.lax.top_k(gates, k)                     # (B,S,K)
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
 
-    sel = jax.nn.one_hot(topi, e, dtype=jnp.float32)             # (B,S,K,E)
-    flat = sel.reshape(b, s * k, e)
-    pos = jnp.cumsum(flat, axis=1) - flat                        # slots before me
-    pos = jnp.sum(flat * pos, axis=-1).reshape(b, s, k).astype(jnp.int32)  # (B,S,K)
-    keep = (pos < c).astype(jnp.float32)
-    pos_oh = jax.nn.one_hot(pos, c, dtype=jnp.float32)           # (B,S,K,C)
+    with jax.named_scope("moe_dispatch"):
+        sel = jax.nn.one_hot(topi, e, dtype=jnp.float32)         # (B,S,K,E)
+        flat = sel.reshape(b, s * k, e)
+        pos = jnp.cumsum(flat, axis=1) - flat            # slots before me
+        pos = jnp.sum(flat * pos, axis=-1).reshape(b, s, k).astype(jnp.int32)
+        keep = (pos < c).astype(jnp.float32)
+        pos_oh = jax.nn.one_hot(pos, c, dtype=jnp.float32)       # (B,S,K,C)
 
-    # dispatch[b,s,e,c] / combine[b,s,e,c]
-    dispatch = jnp.einsum("bske,bskc->bsec", sel * keep[..., None], pos_oh)
-    combine = jnp.einsum("bske,bskc->bsec", sel * (topv * keep)[..., None], pos_oh)
+        # dispatch[b,s,e,c] / combine[b,s,e,c]
+        dispatch = jnp.einsum("bske,bskc->bsec", sel * keep[..., None], pos_oh)
+        combine = jnp.einsum("bske,bskc->bsec",
+                             sel * (topv * keep)[..., None], pos_oh)
 
-    xin = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)  # (E,B,C,D)
-    xin = xin.reshape(e, b * c, d)
-    h = common.linear_apply(p["up"], xin, specs.up, ctx)
-    act = common.activation(specs.act)
-    if specs.gated:
-        g, u = jnp.split(h, 2, axis=-1)
-        h = common.gated(act, g, u, x.dtype)
-    else:
-        h = act(h.astype(jnp.float32)).astype(x.dtype)
-    h = common.linear_apply(p["down"], h, specs.down, ctx)
-    h = h.reshape(e, b, c, d)
-    y = jnp.einsum("ebcd,bsec->bsd", h, combine.astype(x.dtype))
+        xin = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)
+        xin = xin.reshape(e, b * c, d)                           # (E,B·C,D)
+
+    with jax.named_scope("moe_experts"):
+        h = common.linear_apply(p["up"], xin, specs.up, ctx)
+        act = common.activation(specs.act)
+        if specs.gated:
+            g, u = jnp.split(h, 2, axis=-1)
+            h = common.gated(act, g, u, x.dtype)
+        else:
+            h = act(h.astype(jnp.float32)).astype(x.dtype)
+        h = common.linear_apply(p["down"], h, specs.down, ctx)
+
+    with jax.named_scope("moe_combine"):
+        h = h.reshape(e, b, c, d)
+        y = jnp.einsum("ebcd,bsec->bsd", h, combine.astype(x.dtype))
 
     if specs.shared is not None:
-        y = y + ffn.ffn_apply(p["shared"], x, specs.shared, ctx)
+        with jax.named_scope("moe_shared"):
+            y = y + ffn.ffn_apply(p["shared"], x, specs.shared, ctx)
 
     # aux side-car: load-balancing loss term (Switch-style) + routing stats
     density = jnp.mean(jnp.sum(sel, axis=2), axis=(0, 1))        # (E,) token frac
@@ -146,5 +161,6 @@ def moe_apply(p, x, specs: MoESpecs, ctx: ModelCtx):
         "loss": e * jnp.sum(density * router_prob),
         "expert_tokens": jnp.sum(kept, axis=(0, 1, 2)).astype(jnp.int32),
         "dropped": jnp.sum(1.0 - keep).astype(jnp.int32),
+        "rows": e * b * c,
     }
     return y, aux

@@ -122,10 +122,11 @@ def _moe_ffn(p, x, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCtx):
     """Shared serve-path FFN tail: residual add + optional MoE stats.
 
     Returns (x, st) where st is the block's routing-stat dict
-    ({"expert_tokens": (E,) i32, "dropped": i32}) iff this is an MoE block
-    and ctx.moe_stats is on, else None — the top-level entry points sum the
-    dicts across blocks (None is the empty contribution, so dense blocks in
-    a mixed pattern keep the scan carry structure constant)."""
+    ({"expert_tokens": (E,) i32, "dropped": i32, "rows": i32}) iff this is
+    an MoE block and ctx.moe_stats is on, else None — the top-level entry
+    points sum the dicts across blocks (None is the empty contribution, so
+    dense blocks in a mixed pattern keep the scan carry structure
+    constant)."""
     st = None
     if bs.ffn is None:
         return x, st
@@ -133,7 +134,8 @@ def _moe_ffn(p, x, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCtx):
     if bs.is_moe:
         y, a = moe.moe_apply(p["ffn"], h2, bs.ffn, ctx)
         if ctx.moe_stats:
-            st = {"expert_tokens": a["expert_tokens"], "dropped": a["dropped"]}
+            st = {"expert_tokens": a["expert_tokens"], "dropped": a["dropped"],
+                  "rows": jnp.int32(a["rows"])}
     else:
         y = ffn.ffn_apply(p["ffn"], h2, bs.ffn, ctx)
     return common.residual(x, y), st
@@ -142,7 +144,7 @@ def _moe_ffn(p, x, bs: BlockSpecs, cfg: ArchConfig, ctx: ModelCtx):
 def _moe_zero(cfg: ArchConfig):
     """Zero routing-stat accumulator — the scan-carry seed when stats are on."""
     return {"expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
-            "dropped": jnp.int32(0)}
+            "dropped": jnp.int32(0), "rows": jnp.int32(0)}
 
 
 def _moe_add(tot, st):
