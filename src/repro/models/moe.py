@@ -87,8 +87,13 @@ def moe_init(rng, specs: MoESpecs, dtype=jnp.float32):
 
 
 def _capacity(s: int, specs: MoESpecs) -> int:
+    """Slots per expert and batch row: the capacity factor's share, rounded
+    up to a multiple of 4 and at least 4, but never more than s. top_k picks
+    distinct experts, so one row gives an expert at most s assignments: the
+    cap drops nothing the uncapped value keeps, and a decode (s = 1) computes
+    one row per slot."""
     c = int(s * specs.top_k / specs.n_experts * specs.capacity_factor)
-    return max(4, (c + 3) // 4 * 4)
+    return min(s, max(4, (c + 3) // 4 * 4))
 
 
 def moe_apply(p, x, specs: MoESpecs, ctx: ModelCtx):

@@ -80,7 +80,8 @@ def test_rehearsal_of_the_cell(trace):
     count by hand. Every request is admitted in the first tick and none
     completes before its 640th token, so the window only decodes: each
     tick routes 4 slots x top-2 assignments in each layer and computes
-    8 experts x 4 slots x 4 capacity rows (decode capacity is at least 4)."""
+    8 experts x 4 slots x 1 capacity row (a decode's capacity is its one
+    token a slot)."""
     bench = spec.benchmark()
     cell = dict(spec.cell(bench, CELL))
     args = types.SimpleNamespace(workload=CELL, seed=SEED, seconds=1.0,
@@ -101,7 +102,7 @@ def test_rehearsal_of_the_cell(trace):
                                    "expert_row_live_share"}
     assert res["metrics"]["batch_occupancy"]["value"] == 100.0
     assert res["metrics"]["expert_row_live_share"]["value"] == \
-        100.0 * (4 * 2) / (8 * 4 * 4)
+        100.0 * (4 * 2) / (8 * 4 * 1)
 
 
 def _serve_fixed(conf, ticks: int):
@@ -150,22 +151,23 @@ def test_expert_rows_counted_by_hand():
     """(c) moe_expert_rows after one prefill and one decode tick is
     E x B·C in every layer of each call. A 40-token prompt is padded to the
     64 bucket: capacity int(64 x 2 / 8 x 1.25) = 20, so 8 x 1 x 20 = 160
-    rows a layer; a decode over the 4 slots at capacity 4 computes
-    8 x 4 x 4 = 128 a layer. The admitting tick runs the prefill and then
-    decodes; the next tick only decodes."""
+    rows a layer; a decode over the 4 slots at capacity 1 (at most one
+    assignment an expert from one token) computes 8 x 4 x 1 = 32 a layer.
+    The admitting tick runs the prefill and then decodes; the next tick
+    only decodes."""
     from repro.launch.serve import Request
     cfg, srv = client.build_server(tiny_conf(), tiny_mix(), 5)
     layers = cfg.n_layers
     srv.submit(Request(0, np.arange(40, dtype=np.int32), 8))
     srv.step()
     assert srv._bucket(40) == 64 and srv.phys_slots == 4
-    assert srv.stats["moe_expert_rows"] == layers * (160 + 128)
+    assert srv.stats["moe_expert_rows"] == layers * (160 + 32)
     # every row of the bucket and every slot is routed, top-2
     assert srv.stats["moe_routed"] == layers * 2 * (64 + 4)
     before = dict(srv.stats)
     srv.step()
     assert srv.stats["moe_expert_rows"] - before["moe_expert_rows"] == \
-        layers * 128
+        layers * 32
     assert srv.stats["moe_routed"] - before["moe_routed"] == layers * 2 * 4
     assert srv.stats["moe_dropped"] == before["moe_dropped"]
 

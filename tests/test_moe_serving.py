@@ -202,3 +202,54 @@ def test_ep_serve_token_exact_vs_single_device():
     r = _run(SCRIPT_SERVE)
     assert r.returncode == 0, r.stderr[-4000:]
     assert "MOE_SERVE_OK" in r.stdout, r.stdout[-2000:]
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("policy", ["ternary", "int8"])
+@pytest.mark.parametrize("b", [1, 4])
+@pytest.mark.parametrize("s", [1, 2, 3])
+def test_short_call_capacity_is_exact(s, b, policy, backend, monkeypatch):
+    """A call of S < 4 tokens a row runs at capacity S, and that drops
+    nothing the floor of 4 kept: top_k picks distinct experts, so a row
+    gives an expert at most S assignments. The block is bit-equal to the
+    same call forced to capacity 4, with the same per-expert counts, no
+    drops and E·B·S expert rows. Ternary runs the popcount cell; the
+    pallas backend runs the kernels interpreted on the CPU."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.core import qlinear
+    from repro.core.precision import get_policy
+    from repro.models import moe
+    from repro.models.common import ModelCtx
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b").reduced(),
+                              n_experts=8, top_k=3, capacity_factor=1.25)
+    specs = moe.moe_specs(cfg, get_policy(policy))
+    p = moe.moe_init(jax.random.PRNGKey(11), specs)
+    sp = {nm: qlinear.pack_params(p[nm], getattr(specs, nm))
+          for nm in ("router", "up", "down")}
+    sp["shared"] = {nm: qlinear.pack_params(p["shared"][nm],
+                                            getattr(specs.shared, nm))
+                    for nm in ("up", "down")}
+    ctx = ModelCtx(mode="serve", backend=backend, impl="popcount",
+                   dtype=jnp.bfloat16)
+    x = (jax.random.normal(jax.random.PRNGKey(12), (b, s, cfg.d_model))
+         * 0.5).astype(jnp.bfloat16)
+    assert moe._capacity(s, specs) == s
+    apply = lambda: jax.jit(lambda q, v: moe.moe_apply(q, v, specs, ctx))
+    y, aux = apply()(sp, x)
+    monkeypatch.setattr(moe, "_capacity", lambda s_, specs_: 4)
+    y4, aux4 = apply()(sp, x)
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(y4, np.float32))
+    np.testing.assert_array_equal(np.asarray(aux["expert_tokens"]),
+                                  np.asarray(aux4["expert_tokens"]))
+    assert int(aux["expert_tokens"].sum()) == b * s * specs.top_k
+    assert int(aux["dropped"]) == int(aux4["dropped"]) == 0
+    assert int(aux["rows"]) == specs.n_experts * b * s
+    assert int(aux4["rows"]) == specs.n_experts * b * 4
